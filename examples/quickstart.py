@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 # --- 1. the policy layer --------------------------------------------------
 from repro.core import sa_cache
-from repro.kernels.ops import flush_scores as flush_scores_kernel
+from repro.kernels.flush_score import flush_scores as flush_scores_kernel
 
 cache = sa_cache.make_cache(num_sets=4, set_size=12)
 for tag in range(40):                       # fill with pages, some dirty
@@ -23,7 +23,8 @@ for tag in range(40):                       # fill with pages, some dirty
 scores = sa_cache.flush_scores(cache)
 print("flush scores (JAX twin):\n", np.asarray(scores))
 kscores = flush_scores_kernel(cache.hits, cache.clock,
-                              cache.tags != sa_cache.EMPTY)
+                              cache.tags != sa_cache.EMPTY,
+                              interpret=jax.default_backend() != "tpu")
 assert (np.asarray(kscores) == np.asarray(scores)).all()
 print("Pallas flush_score kernel matches the policy layer\n")
 

@@ -190,7 +190,9 @@ class CheckpointManager:
         self.stats["restores"] += 1
 
         leaves_like = flatten_with_paths(like)
-        ordered = [out[k] for k in leaves_like]
+        # np.save keeps bfloat16 only as raw 2-byte voids: the template's
+        # dtype gives the bytes their type back
+        ordered = [out[k].view(leaf.dtype) for k, leaf in leaves_like.items()]
         treedef = jax.tree_util.tree_structure(like)
         if shardings is not None:
             sh_leaves = jax.tree_util.tree_leaves(shardings)

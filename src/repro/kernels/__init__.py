@@ -1,9 +1,10 @@
 """Pallas TPU kernels for the compute hot-spots.
 
-Three kernels, each with the required triple:
-  <name>.py  -- pl.pallas_call + explicit BlockSpec VMEM tiling
-  ops.py     -- jit'd public wrappers (interpret=True on CPU backends)
-  ref.py     -- pure-jnp oracles the tests allclose against
+Each kernel is a jitted function in <name>.py (pl.pallas_call + explicit
+BlockSpec VMEM tiling) with a pure-jnp oracle in ref.py that the tests
+allclose against. A kernel compiles to Mosaic for the TPU. ``interpret=True``
+runs the same kernel body op by op on any backend; only the caller chooses
+it (the CPU tests do). Without it, a call on any other backend raises.
 
 flash_attention  train/prefill attention (causal/SWA/softcap/GQA) -- removes
                  the S^2 logits HBM round-trip that dominates the baseline

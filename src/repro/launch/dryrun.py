@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes, and extract the roofline terms from the compiled artifact.
 
@@ -16,6 +13,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 from pathlib import Path
 
@@ -208,6 +206,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
 
 
 def main():
+    # before the first device query; importing this module changes nothing
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

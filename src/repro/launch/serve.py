@@ -2,6 +2,9 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --preset smoke \
       --requests 12 --max-new 24
+
+``--preset full`` serves the configuration at its published widths and
+depth (one v5e chip holds granite-moe-1b-a400m, not qwen3-8b).
 """
 from __future__ import annotations
 
@@ -13,6 +16,7 @@ import numpy as np
 import jax
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import init_params
 from repro.serving import ServeEngine
 
@@ -20,7 +24,7 @@ from repro.serving import ServeEngine
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
-    ap.add_argument("--preset", default="smoke", choices=["smoke"])
+    ap.add_argument("--preset", default="smoke", choices=["smoke", "full"])
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -31,8 +35,11 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = reduced(get_config(args.arch))
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
+    cfg = get_config(args.arch)
+    if args.preset == "smoke":
+        cfg = reduced(cfg)
+    params = jax.jit(init_params, static_argnums=1)(
+        jax.random.PRNGKey(args.seed), cfg)
     eng = ServeEngine(cfg, params, max_batch=args.max_batch,
                       page_size=args.page, num_sets=args.num_sets,
                       set_size=args.set_size,
@@ -56,4 +63,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

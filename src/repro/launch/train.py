@@ -1,8 +1,11 @@
 """End-to-end training driver.
 
-Runs on whatever devices exist (CPU: 1): reduced configs train for real;
-full configs are for the dry-run meshes. Wires together every substrate:
-data pipeline (prefetched), pjit'd train step with the sharding rules, AdamW,
+Runs on whatever devices exist, on a (devices, 1) ``("data", "model")``
+mesh: ``--preset smoke`` trains a reduced config (CPU: 1 device);
+``--preset full`` trains at published widths (granite-moe-1b-a400m needs the
+four chips of a v5e host for its optimizer state). Wires together every
+substrate: data pipeline (prefetched), pjit'd train step with the sharding
+rules, AdamW,
 async checkpointing (the paper's flusher/queues), gradient compression on
 multi-pod meshes, and restart/resume.
 
@@ -12,22 +15,42 @@ multi-pod meshes, and restart/resume.
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import numpy as np
 
 import jax
-from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, reduced
 from repro.data import Prefetcher, SyntheticLM, make_global_batch
-from repro.distributed.sharding import data_spec, param_specs
+from repro.distributed.sharding import data_spec, param_specs, shape_shardings
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models.transformer import init_params
 from repro.optim import AdamWState, adamw_init
+
+
+def init_state(cfg, mesh, seed: int):
+    """Parameters and AdamW state, each created on the devices its sharding
+    rule names: at published widths the optimizer state fits no one chip.
+
+    Returns (params, opt, (param_shardings, opt_shardings))."""
+    key = jax.random.PRNGKey(seed)
+    init = functools.partial(init_params, cfg=cfg)
+    shapes = jax.eval_shape(init, key)
+    p_specs = param_specs(shapes, mesh)
+    opt_shapes = jax.eval_shape(adamw_init, shapes)
+    opt_specs = AdamWState(step=P(), m=p_specs, v=p_specs,
+                           master=p_specs if opt_shapes.master is not None
+                           else None)
+    p_sh, opt_sh = shape_shardings(p_specs, mesh), shape_shardings(opt_specs, mesh)
+    params = jax.jit(init, out_shardings=p_sh)(key)
+    opt = jax.jit(adamw_init, out_shardings=opt_sh)(params)
+    return params, opt, (p_sh, opt_sh)
 
 
 def main(argv=None):
@@ -52,15 +75,7 @@ def main(argv=None):
         cfg = reduced(cfg, max_seq=max(args.seq, 128))
     mesh = make_host_mesh()
 
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
-    opt = adamw_init(params)
-    p_specs = param_specs(params, mesh)
-    sh = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
-                                is_leaf=lambda x: isinstance(x, P))
-    params = jax.device_put(params, sh(p_specs))
-    opt_specs = AdamWState(step=P(), m=p_specs, v=p_specs,
-                           master=p_specs if opt.master is not None else None)
-    opt = jax.device_put(opt, sh(opt_specs))
+    params, opt, shardings = init_state(cfg, mesh, args.seed)
 
     step_fn = jax.jit(
         make_train_step(cfg, mesh, peak_lr=args.lr, total_steps=args.steps,
@@ -73,7 +88,7 @@ def main(argv=None):
         ckpt = CheckpointManager(args.ckpt_dir)
         if args.resume and ckpt.latest_step() is not None:
             start_step, (params, opt) = ckpt.restore(
-                (params, opt), shardings=(sh(p_specs), sh(opt_specs)))
+                (params, opt), shardings=shardings)
             start_step += 1
             print(f"resumed from step {start_step - 1}")
 
@@ -129,4 +144,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

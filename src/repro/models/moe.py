@@ -131,8 +131,6 @@ def moe_ffn(x, params, cfg: ModelConfig, mesh=None,
     if cfg.moe_experts % ep_size != 0:
         ep_size = 1  # fall back to pure TP when E doesn't divide the axis
 
-    from jax.experimental.shard_map import shard_map
-
     dp = P(dp_axes, None, None)
     if mode == "tp":
         # expert-TP: every shard holds a d_ff/TP slice of EVERY expert and
@@ -151,11 +149,11 @@ def moe_ffn(x, params, cfg: ModelConfig, mesh=None,
                      ep_size=ep_size, stats_axes=dp_axes)
         wspec_up = P(ep_axis if ep_size > 1 else None, None, tp_axis)
         wspec_dn = P(ep_axis if ep_size > 1 else None, tp_axis, None)
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(dp, {"router": P(), "w_gate": wspec_up,
                        "w_up": wspec_up, "w_down": wspec_dn}),
         out_specs=(dp, {"lb": P(), "z": P()}),
-        check_rep=False,
+        check_vma=False,
     )(x, params)
     return out

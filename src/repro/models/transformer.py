@@ -449,6 +449,20 @@ def decode_step(params, tokens, cache: DecodeCache, cfg: ModelConfig, *,
 # Prefill: trunk forward + cache construction
 # ---------------------------------------------------------------------------
 
+def _ring(x, cap: int, fill):
+    """The ring-buffer layout of (B, S, ...) rows in ``cap`` slots: row t in
+    slot t % cap, only the last ``cap`` rows kept, empty slots ``fill``.
+
+    Built from a pad or a roll (the layout is static): the equivalent
+    scatter crashes the TPU compiler's fusion pass (jax 0.9.0, v5e)."""
+    s = x.shape[1]
+    if s <= cap:
+        pad = [(0, 0)] * x.ndim
+        pad[1] = (0, cap - s)
+        return jnp.pad(x, pad, constant_values=fill)
+    return jnp.roll(x[:, -cap:], s % cap, axis=1)
+
+
 def prefill(params, tokens, cfg: ModelConfig, max_seq: int, *,
             positions=None, enc_frames=None, vis_embeds=None, mesh=None):
     """Process the prompt, build the decode cache. Returns (last_logits, cache)."""
@@ -473,16 +487,10 @@ def prefill(params, tokens, cfg: ModelConfig, max_seq: int, *,
                 hpre = L.rmsnorm(xc, p["norm"], cfg.norm_eps)
                 k, v = L.project_kv(hpre, p["attn"], cfg, positions)
                 cap = _attn_cache_cap(spec, max_seq)
-                kc = jnp.zeros((b, cap, cfg.n_kv_heads, cfg.head_dim), k.dtype)
-                vc = jnp.zeros_like(kc)
-                kp = jnp.full((b, cap), KPOS_INVALID, jnp.int32)
-                w = min(s, cap)
-                sl = (s - w + jnp.arange(w)) % cap
-                kc = kc.at[:, sl].set(k[:, -w:])
-                vc = vc.at[:, sl].set(v[:, -w:])
-                kp = kp.at[:, sl].set(jnp.broadcast_to(
-                    (s - w + jnp.arange(w))[None, :], (b, w)))
-                caches.append({"k": kc, "v": vc, "kpos": kp})
+                pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
+                                       (b, s))
+                caches.append({"k": _ring(k, cap, 0), "v": _ring(v, cap, 0),
+                               "kpos": _ring(pos, cap, KPOS_INVALID)})
                 xc, aux = _apply_sublayer(xc, p, spec, cfg, positions=positions,
                                           mesh=mesh, enc_out=enc_out, aux=aux)
             else:

@@ -17,6 +17,7 @@ inference feature; stats expose exactly the quantities the paper reports
 """
 from __future__ import annotations
 
+import functools
 import itertools
 from dataclasses import dataclass, field
 from typing import Optional
@@ -51,7 +52,8 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
                  page_size: int = 16, num_sets: int = 32, set_size: int = 4,
                  max_pages: int = 64, use_flusher: bool = True,
-                 use_kernel: bool = False, seed: int = 0):
+                 use_kernel: bool = False, interpret: bool = False,
+                 seed: int = 0):
         assert cfg.has_attention or cfg.family == "ssm"
         self.cfg = cfg
         self.params = params
@@ -67,8 +69,12 @@ class ServeEngine:
                                 copy_out=self._copy_out, copy_in=self._copy_in,
                                 # paper: trigger at half the set (6 of 12)
                                 flush_trigger=max(0, set_size // 2 - 1))
+        # one compile per (prompt length, padded length), not one per call
+        self._prefill = jax.jit(functools.partial(T.prefill, cfg=cfg),
+                                static_argnames="max_seq")
         self.step_fn = make_paged_decode_step(cfg, page_size=page_size,
-                                              use_kernel=use_kernel)
+                                              use_kernel=use_kernel,
+                                              interpret=interpret)
         self._attn_positions = [i for i, s in enumerate(cfg.block)
                                 if s.kind == "attn"]
         self._reqs: dict[int, Request] = {}
@@ -234,7 +240,7 @@ class ServeEngine:
         s = len(tokens)
         pad = len(req.pages) * self.page
         toks = jnp.asarray(tokens, jnp.int32)[None]
-        logits, cache = T.prefill(self.params, toks, cfg, max_seq=pad)
+        logits, cache = self._prefill(self.params, toks, max_seq=pad)
         new_pools = list(self.pools)
         for i, spec in enumerate(cfg.block):
             lc = cache.layers[i]

@@ -17,7 +17,7 @@ from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.moe import moe_ffn
 from repro.models.ssm import init_mamba_state, mamba_decode_step
-from repro.kernels import ops as kops
+from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ref import paged_attention_ref
 
 
@@ -39,12 +39,15 @@ def init_pools(cfg: ModelConfig, *, num_pages: int, page_size: int,
 
 
 def make_paged_decode_step(cfg: ModelConfig, *, page_size: int,
-                           use_kernel: bool = False, mesh=None):
+                           use_kernel: bool = False, interpret: bool = False,
+                           mesh=None):
     """Returns jitted ``step(params, pools, tokens, lengths, page_table,
     active) -> (logits, new_pools)``.
 
     tokens: (B, 1); lengths: (B,); page_table: (B, max_pages) pool ids;
     active: (B,) bool — inactive rows compute but their state is masked out.
+    ``use_kernel`` runs attention through the Pallas paged kernel, which
+    compiles for the TPU only unless ``interpret`` is set.
     """
 
     def attn_sublayer(x, p, layer_pool, lengths, page_table, active, positions):
@@ -70,8 +73,9 @@ def make_paged_decode_step(cfg: ModelConfig, *, page_size: int,
             jnp.where(active[:, None, None], v[:, 0],
                       layer_pool["v"][pids, offs]))
         if use_kernel:
-            out = kops.paged_attention(q[:, 0], k_pool, v_pool, page_table,
-                                       lengths + 1, softcap=cfg.attn_softcap)
+            out = paged_attention(q[:, 0], k_pool, v_pool, page_table,
+                                  lengths + 1, softcap=cfg.attn_softcap,
+                                  interpret=interpret)
             out = out.reshape(b, 1, h * hd)
         else:
             out = paged_attention_ref(q[:, 0], k_pool, v_pool, page_table,

@@ -10,22 +10,25 @@ import jax.numpy as jnp
 from repro.checkpoint import CheckpointManager
 
 
-def _tree(seed):
+def _tree(seed, dtype=jnp.float32):
     k = jax.random.PRNGKey(seed)
-    return {"a": jax.random.normal(k, (32, 16)),
-            "b": {"w": jax.random.normal(k, (8,)),
+    return {"a": jax.random.normal(k, (32, 16), dtype),
+            "b": {"w": jax.random.normal(k, (8,), dtype),
                   "s": jnp.asarray(seed, jnp.int32)}}
 
 
-def test_roundtrip(tmp_path):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_roundtrip(tmp_path, dtype):
     m = CheckpointManager(tmp_path, n_targets=2)
-    t = _tree(0)
+    t = _tree(0, dtype)
     m.save_async(0, t)
     assert m.wait_for_commit(0, 30)
     step, got = m.restore(t)
     assert step == 0
     for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(got)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b))
+        assert b.dtype == a.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
     m.close()
 
 

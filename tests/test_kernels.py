@@ -2,6 +2,7 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention
@@ -113,6 +114,26 @@ def test_paged_attention_softcap():
     want = ref.paged_attention_ref(q, kp, vp, table, lengths, softcap=30.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
+
+
+@pytest.mark.parametrize("kernel", ["flash", "paged", "flush"])
+def test_kernel_off_tpu_needs_explicit_interpret(kernel):
+    """No silent fallback: off the TPU a kernel runs only when the caller
+    asks for interpret mode."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the kernels compile on the TPU")
+    q = _rand((1, 16, 2, 32), jnp.float32)
+    call = {
+        "flash": lambda: flash_attention(q, q, q),
+        "paged": lambda: paged_attention(
+            q[0], q.reshape(2, 8, 2, 32), q.reshape(2, 8, 2, 32),
+            jnp.zeros((16, 2), jnp.int32), jnp.ones((16,), jnp.int32)),
+        "flush": lambda: flush_scores(jnp.zeros((8, 4), jnp.int32),
+                                      jnp.zeros((8,), jnp.int32),
+                                      jnp.ones((8, 4), bool)),
+    }[kernel]
+    with pytest.raises(ValueError, match="interpret"):
+        call()
 
 
 @pytest.mark.parametrize("ns,ss", [(100, 12), (1000, 12), (64, 7), (513, 16),

@@ -70,7 +70,7 @@ def test_engine_with_paged_kernel(tiny_model):
     """Same outputs when attention runs through the Pallas paged kernel."""
     cfg, params = tiny_model
     eng = ServeEngine(cfg, params, max_batch=2, page_size=8, num_sets=16,
-                      set_size=4, use_kernel=True)
+                      set_size=4, use_kernel=True, interpret=True)
     prompts = [[5, 7, 11], [40, 41, 42, 43, 44]]
     rids = [eng.submit(p, max_new=6) for p in prompts]
     eng.run(100)
