@@ -1,6 +1,5 @@
 """Benchmark aggregator: one section per paper table/figure + the
-beyond-paper serving benchmark + the roofline table (if dry-run artifacts
-exist).
+roofline table (if dry-run artifacts exist).
 
 Every registered section runs even if an earlier one fails its self-check or
 raises — a single broken sweep must not mask the rest (the same failure mode
@@ -60,8 +59,6 @@ _SECTIONS: list[tuple[str, str, str, bool]] = [
      "Paper -- Table 1 / Table 2 / Figure 2 (raw array under GC)", False),
     ("paper_figs", "paper_figs",
      "Paper -- Figures 3-5, Table 3 (SAFS + dirty-page flusher)", False),
-    ("serving", "serving_bench",
-     "Beyond-paper -- flusher in the paged-KV serving engine", False),
     ("roofline", "roofline",
      "Roofline -- per (arch x shape), single-pod 16x16 (from dry-run)",
      False),

@@ -14,10 +14,16 @@ Scheduler loop (host) + jitted paged decode step (device):
 This is the paper's cache+flusher+queues stack serving as a first-class
 inference feature; stats expose exactly the quantities the paper reports
 (extra writeback, stall counts, queue discards).
+
+Each phase of a step and of an admission opens a ``jax.profiler`` span
+named ``serve.*`` (``serve.step`` > ``serve.admit`` > ``serve.prefill`` ...,
+``serve.grow``, ``serve.dispatch``, ``serve.sync``, ``serve.bookkeep``,
+``serve.requeue``); the pool's IO workers open ``serve.offload_io`` and
+``serve.fetch_io``. A running profiler writes them on the device trace's
+clock; without one a span costs about a microsecond.
 """
 from __future__ import annotations
 
-import functools
 import itertools
 from dataclasses import dataclass, field
 from typing import Optional
@@ -26,6 +32,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as T
@@ -45,7 +52,6 @@ class Request:
     row: int = -1
     length: int = 0
     pages: list[int] = field(default_factory=list)     # tags, in order
-    stall_steps: int = 0
 
 
 class ServeEngine:
@@ -69,9 +75,12 @@ class ServeEngine:
                                 copy_out=self._copy_out, copy_in=self._copy_in,
                                 # paper: trigger at half the set (6 of 12)
                                 flush_trigger=max(0, set_size // 2 - 1))
-        # one compile per (prompt length, padded length), not one per call
-        self._prefill = jax.jit(functools.partial(T.prefill, cfg=cfg),
-                                static_argnames="max_seq")
+        def prefill(params, tokens, max_seq):
+            return T.prefill(params, tokens, cfg, max_seq=max_seq)
+
+        # one compile per (prompt length, padded length), not one per call;
+        # the module is named jit_prefill
+        self._prefill = jax.jit(prefill, static_argnames="max_seq")
         self.step_fn = make_paged_decode_step(cfg, page_size=page_size,
                                               use_kernel=use_kernel,
                                               interpret=interpret)
@@ -88,6 +97,8 @@ class ServeEngine:
         self._pools_lock = __import__("threading").Lock()
         self.preemptions = 0
         self.blocking_offloads = 0
+        self.unflushed_at_preempt = 0    # full pages the flusher had not cleaned
+        self._steps = 0
 
     # ------------------------------------------------------------- tags
     def _tag(self, rid: int, page_idx: int) -> int:
@@ -164,21 +175,22 @@ class ServeEngine:
         return max(active, key=lambda r: r.rid)        # youngest first (LIFO)
 
     def _preempt(self, req: Request) -> None:
-        self.preemptions += 1
-        # partial (dirty, non-full) pages + any un-offloaded full pages must
-        # reach the host tier before their slots can be reused
-        for tag in req.pages:
-            pid = self.pool.alloc.where.get(tag)
-            if pid is not None and self.pool.alloc.dirty[pid]:
-                if self.pool.alloc.full[pid] and self.use_flusher:
-                    req.stall_steps += 1   # flusher hadn't gotten to it yet
-                self.pool.offload_now(tag)
-                self.blocking_offloads += 1
-        self.pool.alloc.set_pinned(req.pages, False)
-        self._rows[req.row] = None
-        self._tables[req.row, :] = self.scratch_page
-        req.state = "preempted"
-        req.row = -1
+        with TraceAnnotation("serve.preempt", rid=req.rid):
+            self.preemptions += 1
+            # partial (dirty, non-full) pages + any un-offloaded full pages
+            # must reach the host tier before their slots can be reused
+            for tag in req.pages:
+                pid = self.pool.alloc.where.get(tag)
+                if pid is not None and self.pool.alloc.dirty[pid]:
+                    if self.pool.alloc.full[pid] and self.use_flusher:
+                        self.unflushed_at_preempt += 1
+                    self.pool.offload_now(tag)
+                    self.blocking_offloads += 1
+            self.pool.alloc.set_pinned(req.pages, False)
+            self._rows[req.row] = None
+            self._tables[req.row, :] = self.scratch_page
+            req.state = "preempted"
+            req.row = -1
 
     def _free(self, req: Request) -> None:
         self.pool.alloc.free(req.pages)
@@ -225,60 +237,66 @@ class ServeEngine:
         req.row, req.state = row, "active"
         self._rows[row] = rid
         if resume:
-            # fetch by LOGICAL page index, not by the (lossy) tag list —
-            # a page evicted while preempted lives only in the host tier
-            fetchable = [self._tag(rid, i) for i in range(n_pages)
-                         if self._tag(rid, i) in self.pool.host_tier]
-            self.pool.fetch(fetchable)
-            self._refill_row(req, tokens)
+            with TraceAnnotation("serve.resume_fetch", rid=rid):
+                # fetch by LOGICAL page index, not by the (lossy) tag list —
+                # a page evicted while preempted lives only in the host tier
+                fetchable = [self._tag(rid, i) for i in range(n_pages)
+                             if self._tag(rid, i) in self.pool.host_tier]
+                self.pool.fetch(fetchable)
+                self._refill_row(req, tokens)
         else:
             self._prefill_row(req, tokens)
         return True
 
     def _prefill_row(self, req: Request, tokens: list[int]) -> None:
-        cfg, row = self.cfg, req.row
-        s = len(tokens)
-        pad = len(req.pages) * self.page
-        toks = jnp.asarray(tokens, jnp.int32)[None]
-        logits, cache = self._prefill(self.params, toks, max_seq=pad)
-        new_pools = list(self.pools)
-        for i, spec in enumerate(cfg.block):
-            lc = cache.layers[i]
-            if spec.kind == "attn":
-                k = lc["k"][:, 0]                          # (nb, pad, kvh, hd)
-                v = lc["v"][:, 0]
-                kp, vp = new_pools[i]["k"], new_pools[i]["v"]
+        with TraceAnnotation("serve.prefill", rid=req.rid):
+            cfg, row = self.cfg, req.row
+            s = len(tokens)
+            pad = len(req.pages) * self.page
+            with TraceAnnotation("serve.prefill.forward"):
+                toks = jnp.asarray(tokens, jnp.int32)[None]
+                logits, cache = self._prefill(self.params, toks, max_seq=pad)
+            with TraceAnnotation("serve.prefill.page_write"):
+                new_pools = list(self.pools)
+                for i, spec in enumerate(cfg.block):
+                    lc = cache.layers[i]
+                    if spec.kind == "attn":
+                        k = lc["k"][:, 0]                  # (nb, pad, kvh, hd)
+                        v = lc["v"][:, 0]
+                        kp, vp = new_pools[i]["k"], new_pools[i]["v"]
+                        for tag in req.pages:
+                            pi = tag % MAX_PAGES_PER_SEQ  # page index from tag
+                            pid = self.pool.alloc.where[tag]
+                            sl = slice(pi * self.page, (pi + 1) * self.page)
+                            kp = kp.at[:, pid].set(k[:, sl])
+                            vp = vp.at[:, pid].set(v[:, sl])
+                        new_pools[i] = {"k": kp, "v": vp}
+                    else:
+                        st = new_pools[i]
+                        new_pools[i] = jax.tree.map(
+                            lambda pool, new: pool.at[:, row].set(new[:, 0]),
+                            st, {k: lc[k] for k in st})
+                # NOTE: prefill caches beyond ``s`` are zeros — masked by lengths.
+                self.pools = tuple(new_pools)
+                self._lengths[row] = s
+                self._tables[row, :] = self.scratch_page
                 for tag in req.pages:
-                    pi = tag % MAX_PAGES_PER_SEQ        # page index from tag
-                    pid = self.pool.alloc.where[tag]
-                    sl = slice(pi * self.page, (pi + 1) * self.page)
-                    kp = kp.at[:, pid].set(k[:, sl])
-                    vp = vp.at[:, pid].set(v[:, sl])
-                new_pools[i] = {"k": kp, "v": vp}
-            else:
-                st = new_pools[i]
-                new_pools[i] = jax.tree.map(
-                    lambda pool, new: pool.at[:, row].set(new[:, 0]),
-                    st, {k: lc[k] for k in st})
-        # NOTE: prefill caches beyond ``s`` are zeros — masked by lengths.
-        self.pools = tuple(new_pools)
-        self._lengths[row] = s
-        self._tables[row, :] = self.scratch_page
-        for tag in req.pages:
-            self._tables[row, tag % MAX_PAGES_PER_SEQ] = \
-                self.pool.alloc.where[tag]
-        # the prompt's last-position logits emit the FIRST generated token
-        first = int(jnp.argmax(logits[0, -1]))
-        req.out.append(first)
-        self._last_tok[row] = first
-        req.length = s
-        # full prompt pages are immediately flushable
-        if self.use_flusher:
-            for tag in req.pages:
-                pi = tag % MAX_PAGES_PER_SEQ
-                if (pi + 1) * self.page <= s:
-                    self.pool.alloc.mark_full(tag)
-                    self.pool.note_page_full(self.pool.alloc.set_of(tag))
+                    self._tables[row, tag % MAX_PAGES_PER_SEQ] = \
+                        self.pool.alloc.where[tag]
+            # the prompt's last-position logits emit the FIRST generated token
+            with TraceAnnotation("serve.prefill.first_token"):
+                first = int(jnp.argmax(logits[0, -1]))
+            req.out.append(first)
+            self._last_tok[row] = first
+            req.length = s
+            # full prompt pages are immediately flushable
+            if self.use_flusher:
+                with TraceAnnotation("serve.prefill.mark_full"):
+                    for tag in req.pages:
+                        pi = tag % MAX_PAGES_PER_SEQ
+                        if (pi + 1) * self.page <= s:
+                            self.pool.alloc.mark_full(tag)
+                            self.pool.note_page_full(self.pool.alloc.set_of(tag))
 
     def _refill_row(self, req: Request, tokens: list[int]) -> None:
         """Resume: pages were fetched back by tag; rebuild the table/row."""
@@ -292,67 +310,77 @@ class ServeEngine:
 
     # --------------------------------------------------------------- loop
     def step(self) -> None:
-        # admission
-        for rid in list(self._waiting):
-            if self._admit(rid):
-                self._waiting.remove(rid)
+        self._steps += 1
+        with StepTraceAnnotation("serve.step", step_num=self._steps):
+            self._step()
+
+    def _step(self) -> None:
+        with TraceAnnotation("serve.admit"):
+            for rid in list(self._waiting):
+                if self._admit(rid):
+                    self._waiting.remove(rid)
         active_rows = [i for i, r in enumerate(self._rows) if r is not None]
         if not active_rows:
             return
         # ensure a page exists for the next position of every active row
-        for i in active_rows:
-            rid = self._rows[i]
-            if rid is None:                      # preempted as a victim above
-                continue
-            req = self._reqs[rid]
-            pi = int(self._lengths[i]) // self.page
-            tag = self._tag(req.rid, pi)
-            if self.pool.alloc.where.get(tag) is None:
-                if not self._alloc_page(req, pi):
-                    self._preempt(req)
+        with TraceAnnotation("serve.grow"):
+            for i in active_rows:
+                rid = self._rows[i]
+                if rid is None:                  # preempted as a victim above
                     continue
-                self._tables[i, pi] = self.pool.alloc.where[tag]
+                req = self._reqs[rid]
+                pi = int(self._lengths[i]) // self.page
+                tag = self._tag(req.rid, pi)
+                if self.pool.alloc.where.get(tag) is None:
+                    if not self._alloc_page(req, pi):
+                        self._preempt(req)
+                        continue
+                    self._tables[i, pi] = self.pool.alloc.where[tag]
         active_rows = [i for i, r in enumerate(self._rows) if r is not None]
         if not active_rows:
             return
-        active = np.zeros(self.max_batch, bool)
-        active[active_rows] = True
-        logits, self.pools = self.step_fn(
-            self.params, self.pools,
-            jnp.asarray(self._last_tok[:, None]),
-            jnp.asarray(self._lengths),
-            jnp.asarray(self._tables),
-            jnp.asarray(active))
-        toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
-        # GClock touch: every resident page of every active row was read
-        for i in active_rows:
-            if self._rows[i] is None:
-                continue
-            self.pool.alloc.touch(self._reqs[self._rows[i]].pages)
-        for i in active_rows:
-            if self._rows[i] is None:
-                continue
-            req = self._reqs[self._rows[i]]
-            # the page written this step diverged from any host copy
-            cur_tag = self._tag(req.rid, int(self._lengths[i]) // self.page)
-            self.pool.mark_redirtied(cur_tag)
-            req.out.append(int(toks[i]))
-            self._last_tok[i] = toks[i]
-            self._lengths[i] += 1
-            req.length += 1
-            if self._lengths[i] % self.page == 0 and self.use_flusher:
-                tag = self._tag(req.rid, int(self._lengths[i]) // self.page - 1)
-                self.pool.alloc.mark_full(tag)
-                self.pool.note_page_full(self.pool.alloc.set_of(tag))
-            if len(req.out) >= req.max_new:
-                req.state = "done"
-                self._rows[i] = None
-                self._tables[i, :] = self.scratch_page
-                self._free(req)
+        with TraceAnnotation("serve.dispatch"):
+            active = np.zeros(self.max_batch, bool)
+            active[active_rows] = True
+            logits, self.pools = self.step_fn(
+                self.params, self.pools,
+                jnp.asarray(self._last_tok[:, None]),
+                jnp.asarray(self._lengths),
+                jnp.asarray(self._tables),
+                jnp.asarray(active))
+        with TraceAnnotation("serve.sync"):
+            toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
+        with TraceAnnotation("serve.bookkeep"):
+            # GClock touch: every resident page of every active row was read
+            for i in active_rows:
+                if self._rows[i] is None:
+                    continue
+                self.pool.alloc.touch(self._reqs[self._rows[i]].pages)
+            for i in active_rows:
+                if self._rows[i] is None:
+                    continue
+                req = self._reqs[self._rows[i]]
+                # the page written this step diverged from any host copy
+                cur_tag = self._tag(req.rid, int(self._lengths[i]) // self.page)
+                self.pool.mark_redirtied(cur_tag)
+                req.out.append(int(toks[i]))
+                self._last_tok[i] = toks[i]
+                self._lengths[i] += 1
+                req.length += 1
+                if self._lengths[i] % self.page == 0 and self.use_flusher:
+                    tag = self._tag(req.rid, int(self._lengths[i]) // self.page - 1)
+                    self.pool.alloc.mark_full(tag)
+                    self.pool.note_page_full(self.pool.alloc.set_of(tag))
+                if len(req.out) >= req.max_new:
+                    req.state = "done"
+                    self._rows[i] = None
+                    self._tables[i, :] = self.scratch_page
+                    self._free(req)
         # resumption of preempted requests
-        for req in list(self._reqs.values()):
-            if req.state == "preempted":
-                self._waiting.append(req.rid) if req.rid not in self._waiting else None
+        with TraceAnnotation("serve.requeue"):
+            for req in list(self._reqs.values()):
+                if req.state == "preempted":
+                    self._waiting.append(req.rid) if req.rid not in self._waiting else None
 
     def run(self, max_steps: int = 1000) -> None:
         for _ in range(max_steps):
@@ -370,6 +398,9 @@ class ServeEngine:
             "alloc_failures": s.alloc_failures,
             "preemptions": self.preemptions,
             "blocking_offloads": self.blocking_offloads,
+            "flush_requests": s.flush_requests,
+            "allocs": s.allocs,
+            "unflushed_at_preempt": self.unflushed_at_preempt,
         }
 
     def close(self):

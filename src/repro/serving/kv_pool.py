@@ -27,6 +27,7 @@ from dataclasses import dataclass
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import policies
 from repro.core.flusher import DirtyPageFlusher, FlushRequest, StalenessChecker
@@ -37,6 +38,7 @@ from repro.core.io_queues import HIGH, LOW, IOExecutor, IORequest
 @dataclass
 class PoolStats:
     allocs: int = 0
+    flush_requests: int = 0           # background offloads the flusher queued
     clean_evictions: int = 0
     dirty_evictions: int = 0          # blocking offload on the alloc path
     alloc_failures: int = 0           # -> engine preempts a sequence
@@ -257,20 +259,21 @@ class PagedKVPool:
         import time
         if self._offload_delay:
             time.sleep(self._offload_delay)
+        tag = payload["tag"]
         if payload["op"] == "offload":
-            tag = payload["tag"]
-            data = self._copy_out(tag)
-            if data is not None:
-                with self._lock:
-                    self.host_tier[tag] = data
-                    self.alloc.mark_clean(tag)
-                    self.alloc.stats.offloads += 1
+            with TraceAnnotation("serve.offload_io", tag=tag):
+                data = self._copy_out(tag)
+                if data is not None:
+                    with self._lock:
+                        self.host_tier[tag] = data
+                        self.alloc.mark_clean(tag)
+                        self.alloc.stats.offloads += 1
         else:                                     # fetch (HIGH)
-            tag = payload["tag"]
-            self._copy_in(tag, self.host_tier[tag])
-            with self._lock:
-                self.alloc.mark_clean(tag)        # content == host copy
-                self.alloc.stats.fetches += 1
+            with TraceAnnotation("serve.fetch_io", tag=tag):
+                self._copy_in(tag, self.host_tier[tag])
+                with self._lock:
+                    self.alloc.mark_clean(tag)    # content == host copy
+                    self.alloc.stats.fetches += 1
             payload["done"].release()
 
     # -- flusher pump (paper §3.3) -----------------------------------------
@@ -279,13 +282,15 @@ class PagedKVPool:
         self.pump()
 
     def pump(self, budget: int = 8) -> None:
-        for fr in self.flusher.make_requests(budget, max_visits=16):
-            self.exec.submit(fr.device, IORequest(
-                payload={"op": "offload", "tag": fr.tag, "fr": fr},
-                priority=LOW,
-                is_stale=lambda p, fr=fr: self.checker(fr),
-                on_complete=lambda p, fr=fr: self.flusher.note_flush_done(fr),
-                on_discard=lambda p, fr=fr: self._on_discard(fr)))
+        with TraceAnnotation("serve.pump"):
+            for fr in self.flusher.make_requests(budget, max_visits=16):
+                self.alloc.stats.flush_requests += 1
+                self.exec.submit(fr.device, IORequest(
+                    payload={"op": "offload", "tag": fr.tag, "fr": fr},
+                    priority=LOW,
+                    is_stale=lambda p, fr=fr: self.checker(fr),
+                    on_complete=lambda p, fr=fr: self.flusher.note_flush_done(fr),
+                    on_discard=lambda p, fr=fr: self._on_discard(fr)))
 
     def _on_discard(self, fr: FlushRequest) -> None:
         with self._lock:
