@@ -10,8 +10,8 @@ flash_attention  train/prefill attention (causal/SWA/softcap/GQA) -- removes
                  the S^2 logits HBM round-trip that dominates the baseline
                  roofline memory term.
 paged_attention  decode attention over the SA-cache-managed paged KV pool
-                 (scalar-prefetched page table -- the serving engine's data
-                 plane).
+                 (a scalar-prefetched schedule of each row's live pages --
+                 the serving engine's data plane on the TPU).
 flush_score      the paper's SS3.3.1 GClock distance-score + rank over page
                  sets, vectorized sets-to-sublanes (the host-side hot loop of
                  SAFS adapted to the TPU VPU).

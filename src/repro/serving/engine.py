@@ -58,9 +58,15 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
                  page_size: int = 16, num_sets: int = 32, set_size: int = 4,
                  max_pages: int = 64, use_flusher: bool = True,
-                 use_kernel: bool = False, interpret: bool = False,
+                 use_kernel: Optional[bool] = None, interpret: bool = False,
                  seed: int = 0):
+        """``use_kernel=None`` runs decode attention through the Pallas
+        paged kernel on a TPU (or wherever ``interpret`` is set) and through
+        ``paged_attention_ref`` elsewhere."""
         assert cfg.has_attention or cfg.family == "ssm"
+        if use_kernel is None:
+            use_kernel = interpret or jax.default_backend() == "tpu"
+        self.use_kernel = use_kernel
         self.cfg = cfg
         self.params = params
         self.page = page_size
@@ -98,6 +104,8 @@ class ServeEngine:
         self.preemptions = 0
         self.blocking_offloads = 0
         self.unflushed_at_preempt = 0    # full pages the flusher had not cleaned
+        self.attn_pages_read = 0         # live pages the paged kernel attended
+        self.attn_pages_spanned = 0      # table entries of the decoding rows
         self._steps = 0
 
     # ------------------------------------------------------------- tags
@@ -342,6 +350,11 @@ class ServeEngine:
         with TraceAnnotation("serve.dispatch"):
             active = np.zeros(self.max_batch, bool)
             active[active_rows] = True
+            self.attn_pages_spanned += len(active_rows) * self.max_pages
+            if self.use_kernel:
+                # the step attends over lengths + 1 tokens
+                self.attn_pages_read += int(np.sum(
+                    self._lengths[active_rows] // self.page + 1))
             logits, self.pools = self.step_fn(
                 self.params, self.pools,
                 jnp.asarray(self._last_tok[:, None]),
@@ -401,6 +414,8 @@ class ServeEngine:
             "flush_requests": s.flush_requests,
             "allocs": s.allocs,
             "unflushed_at_preempt": self.unflushed_at_preempt,
+            "attn_pages_read": self.attn_pages_read,
+            "attn_pages_spanned": self.attn_pages_spanned,
         }
 
     def close(self):

@@ -47,7 +47,9 @@ def make_paged_decode_step(cfg: ModelConfig, *, page_size: int,
     tokens: (B, 1); lengths: (B,); page_table: (B, max_pages) pool ids;
     active: (B,) bool — inactive rows compute but their state is masked out.
     ``use_kernel`` runs attention through the Pallas paged kernel, which
-    compiles for the TPU only unless ``interpret`` is set.
+    reads only each row's pages below its length and compiles for the TPU
+    only unless ``interpret`` is set; without it, ``paged_attention_ref``
+    gathers the whole table.
     """
 
     def attn_sublayer(x, p, layer_pool, lengths, page_table, active, positions):
@@ -72,16 +74,16 @@ def make_paged_decode_step(cfg: ModelConfig, *, page_size: int,
         v_pool = layer_pool["v"].at[pids, offs].set(
             jnp.where(active[:, None, None], v[:, 0],
                       layer_pool["v"][pids, offs]))
+        # a row that does not decode reads no page; its output is discarded
+        attn_lengths = jnp.where(active, lengths + 1, 0)
         if use_kernel:
             out = paged_attention(q[:, 0], k_pool, v_pool, page_table,
-                                  lengths + 1, softcap=cfg.attn_softcap,
+                                  attn_lengths, softcap=cfg.attn_softcap,
                                   interpret=interpret)
-            out = out.reshape(b, 1, h * hd)
         else:
             out = paged_attention_ref(q[:, 0], k_pool, v_pool, page_table,
-                                      lengths + 1,
-                                      softcap=cfg.attn_softcap)
-            out = out.reshape(b, 1, h * hd)
+                                      attn_lengths, softcap=cfg.attn_softcap)
+        out = out.reshape(b, 1, h * hd)
         return out @ p["wo"], {"k": k_pool, "v": v_pool}
 
     def step(params, pools, tokens, lengths, page_table, active):

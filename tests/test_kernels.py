@@ -7,7 +7,7 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flush_score import flush_scores
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import pages_per_block, paged_attention
 from repro.kernels import ref
 
 RNG = np.random.default_rng(42)
@@ -84,19 +84,54 @@ PAGED_CASES = [
     (3, 6, 6, 32, 8, 16, 128),
     (1, 16, 8, 64, 64, 4, 8),
 ]
+# Several blocks of pages a row, max_pages not a multiple of the block:
+# lengths end mid-page, mid-block, at a block's edge, at the table's end, and
+# rows of length 1; group 1 (MHA, like OLMoE) and group 2 (like granite).
+# "nan" fills every pool page no live position reads with NaN.
+BLOCK_CASES = [
+    # b, h, kv, hd, page, max_pages, pool, nan
+    (6, 4, 4, 64, 16, 70, 512, False),
+    (6, 8, 4, 64, 16, 70, 512, False),
+    (6, 8, 4, 64, 16, 70, 512, True),
+]
 
 
-@pytest.mark.parametrize("case", _sweep(PAGED_CASES, keep=2))
+def _block_lengths(page, maxp, ppb):
+    block = ppb * page
+    return jnp.asarray([1, page // 2 + 1, block + page + 3, block, 2 * block,
+                        maxp * page], jnp.int32)
+
+
+@pytest.mark.parametrize("case", _sweep(PAGED_CASES, keep=2) + BLOCK_CASES)
 @pytest.mark.parametrize("dtype", _sweep([jnp.float32, jnp.bfloat16]))
 def test_paged_attention_matches_ref(case, dtype):
-    b, h, kv, hd, page, maxp, pool = case
+    b, h, kv, hd, page, maxp, pool = case[:7]
+    nan = len(case) > 7 and case[7]
     q = _rand((b, h, hd), dtype)
     kp = _rand((pool, page, kv, hd), dtype)
     vp = _rand((pool, page, kv, hd), dtype)
     table = jnp.asarray(RNG.integers(0, pool, size=(b, maxp)), jnp.int32)
-    lengths = jnp.asarray(RNG.integers(1, maxp * page, size=(b,)), jnp.int32)
-    out = paged_attention(q, kp, vp, table, lengths, interpret=True)
+    if len(case) > 7:
+        ppb = pages_per_block(page, kv, hd, kp.dtype.itemsize, maxp)
+        assert 1 < ppb < maxp and maxp % ppb, "case must span partial blocks"
+        lengths = _block_lengths(page, maxp, ppb)[:b]
+    else:
+        lengths = jnp.asarray(RNG.integers(1, maxp * page, size=(b,)),
+                              jnp.int32)
+    live = (lengths[:, None] + page - 1) // page > jnp.arange(maxp)
+    if nan:
+        # live pages are distinct; the table's entries at or past each
+        # length all name one page
+        table = jnp.where(live, jnp.arange(b * maxp).reshape(b, maxp) % pool,
+                          pool - 1)
     want = ref.paged_attention_ref(q, kp, vp, table, lengths)
+    if nan:
+        read = jnp.zeros(pool, bool).at[jnp.where(live, table, pool)].set(
+            True, mode="drop")
+        kp = jnp.where(read[:, None, None, None], kp, jnp.nan)
+        vp = jnp.where(read[:, None, None, None], vp, jnp.nan)
+    out = paged_attention(q, kp, vp, table, lengths, interpret=True)
+    assert np.all(np.isfinite(np.asarray(out, np.float32)))
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=tol, rtol=tol)

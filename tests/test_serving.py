@@ -79,6 +79,55 @@ def test_engine_with_paged_kernel(tiny_model):
     eng.close()
 
 
+@pytest.mark.parametrize("backend,interpret,want", [
+    ("cpu", False, False), ("tpu", False, True), ("cpu", True, True)])
+def test_engine_picks_the_kernel_on_tpu(tiny_model, monkeypatch, backend,
+                                        interpret, want):
+    """By default decode attention runs through the Pallas kernel on a TPU
+    (or where interpret mode is asked for) and the reference elsewhere.
+    Only the choice is checked: nothing runs."""
+    cfg, params = tiny_model
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    eng = ServeEngine(cfg, params, max_batch=2, page_size=8, num_sets=4,
+                      set_size=2, interpret=interpret)
+    assert eng.use_kernel is want
+    eng.close()
+    eng = ServeEngine(cfg, params, max_batch=2, page_size=8, num_sets=4,
+                      set_size=2, use_kernel=False)
+    assert eng.use_kernel is False
+    eng.close()
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_attention_page_counters(tiny_model, use_kernel):
+    """``attn_pages_read`` adds up, over the decode steps, the pages below
+    each decoding row's length + 1 when the kernel runs (0 on the reference
+    path); ``attn_pages_spanned`` adds up the decoding rows' table entries."""
+    cfg, params = tiny_model
+    page, max_pages = 8, 8
+    eng = ServeEngine(cfg, params, max_batch=2, page_size=page, num_sets=16,
+                      set_size=4, max_pages=max_pages, use_kernel=use_kernel,
+                      interpret=use_kernel)
+    seen = {"read": 0, "spanned": 0}
+    decode = eng.step_fn
+
+    def counting_step(params, pools, tokens, lengths, table, active):
+        lens = np.asarray(lengths)[np.asarray(active)]
+        seen["read"] += int(np.sum(-(-(lens + 1) // page)))
+        seen["spanned"] += len(lens) * max_pages
+        return decode(params, pools, tokens, lengths, table, active)
+
+    eng.step_fn = counting_step
+    # contexts cross page edges: 3 + 12 and 14 + 12 tokens
+    for prompt in ([5, 7, 11], list(range(40, 54))):
+        eng.submit(prompt, max_new=12)
+    eng.run(100)
+    st = eng.stats()
+    assert st["attn_pages_spanned"] == seen["spanned"] > 0
+    assert st["attn_pages_read"] == (seen["read"] if use_kernel else 0)
+    eng.close()
+
+
 def test_mamba_engine(tiny_model):
     """Attention-free arch: state pages instead of KV pages."""
     cfg = reduced(get_config("mamba2-780m"))
