@@ -16,6 +16,7 @@ ARCH_IDS = [
     "granite-moe-1b-a400m",
     "olmoe-1b-7b",
     "qwen2-vl-72b",
+    "deepseek-v2-lite",
 ]
 
 _MODULES = {
@@ -29,6 +30,7 @@ _MODULES = {
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
     "olmoe-1b-7b": "olmoe_1b_7b",
     "qwen2-vl-72b": "qwen2_vl_72b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

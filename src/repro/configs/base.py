@@ -15,7 +15,7 @@ from dataclasses import dataclass
 class LayerSpec:
     """One sublayer inside the repeating block."""
 
-    kind: str              # "attn" | "mamba"
+    kind: str              # "attn" | "mla" | "mamba"
     ffn: str = "mlp"       # "mlp" | "moe" | "none"
     window: int = 0        # sliding-window size; 0 = full attention
 
@@ -32,6 +32,9 @@ class ModelConfig:
     vocab: int
     head_dim: int = 0                # 0 -> d_model // n_heads
     block: tuple[LayerSpec, ...] = ()  # () -> homogeneous full-attn + mlp
+    # leading layers outside the repeating block: the block's first sublayer
+    # kind with a dense MLP of width d_ff (DeepSeek's first_k_dense_replace)
+    n_dense_layers: int = 0
 
     # attention flavour
     qk_norm: bool = False
@@ -46,12 +49,27 @@ class ModelConfig:
     logit_softcap: float = 0.0
     rope_theta: float = 10000.0
     mrope_sections: tuple[int, ...] = ()   # qwen2-vl M-RoPE (t, h, w) half-dims
+    # YaRN rope scaling (DeepSeek-V2); factor 0 -> plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    # multi-head latent attention (LayerSpec kind "mla"; no query LoRA):
+    # the cache holds the normed kv_lora_rank-wide latent and one roped
+    # qk_rope_head_dim-wide key per token
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MoE
     moe_experts: int = 0
     moe_topk: int = 0
     moe_d_ff: int = 0
     moe_capacity_factor: float = 1.25   # per-expert buffer = T*topk/E * this
+    moe_shared_d_ff: int = 0            # shared experts as one MLP; 0 -> none
+    moe_norm_topk: bool = True          # renormalise the top-k gate weights
     # "ep": experts sharded over data, token all-to-all (paper-standard);
     # "tp": expert weights sharded over model d_ff, output psum — moves
     #       T x d instead of E x C x d per layer (§Perf hillclimb)
@@ -84,12 +102,37 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // max(self.n_heads, 1))
         if not self.block:
             object.__setattr__(self, "block", (LayerSpec(kind="attn", ffn="mlp"),))
-        assert self.n_layers % len(self.block) == 0, (
-            f"{self.name}: n_layers {self.n_layers} not divisible by block {len(self.block)}")
+        assert (self.n_layers - self.n_dense_layers) % len(self.block) == 0, (
+            f"{self.name}: n_layers {self.n_layers} - {self.n_dense_layers} "
+            f"not divisible by block {len(self.block)}")
 
     @property
     def n_blocks(self) -> int:
-        return self.n_layers // len(self.block)
+        return (self.n_layers - self.n_dense_layers) // len(self.block)
+
+    @property
+    def stacks(self) -> tuple[tuple[str, tuple[LayerSpec, ...], int], ...]:
+        """The scanned layer stacks in order, as (params key, sublayer
+        pattern, repeats): the leading dense layers, then the block."""
+        out = []
+        if self.n_dense_layers:
+            dense = dataclasses.replace(self.block[0], ffn="mlp")
+            out.append(("dense", (dense,), self.n_dense_layers))
+        out.append(("blocks", self.block, self.n_blocks))
+        return tuple(out)
+
+    @property
+    def layer_specs(self) -> tuple[LayerSpec, ...]:
+        """Every stack's sublayer pattern, in order: one entry per cache or
+        pool position (``DecodeCache.layers``, the engine's pools)."""
+        return tuple(spec for _, specs, _ in self.stacks for spec in specs)
+
+    @property
+    def mla_cache_width(self) -> int:
+        """Lanes of an MLA cache entry: the latent, the roped key, zeros up
+        to a multiple of 128 (a minor dimension of 576 makes the TPU lay the
+        pool out page-minor, and the paged kernel would copy it each call)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     @property
     def d_inner(self) -> int:          # mamba2 inner width
@@ -101,7 +144,7 @@ class ModelConfig:
 
     @property
     def has_attention(self) -> bool:
-        return any(l.kind == "attn" for l in self.block)
+        return any(l.kind in ("attn", "mla") for l in self.block)
 
     @property
     def sub_quadratic(self) -> bool:
@@ -114,40 +157,50 @@ class ModelConfig:
             return True
         return all(l.kind == "mamba" or l.window > 0 for l in self.block)
 
+    def _sublayer_params(self, spec: LayerSpec) -> int:
+        d, hd = self.d_model, self.head_dim
+        n = d                           # pre-norm
+        if self.post_norms:
+            n += d
+        if spec.kind == "mla":
+            h, r, rope = self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            n += d * h * (self.qk_nope_head_dim + rope)          # wq
+            n += d * (r + rope) + r                              # wkv_a, kv_norm
+            n += r * h * (self.qk_nope_head_dim + self.v_head_dim)   # wkv_b
+            n += h * self.v_head_dim * d                         # wo
+        elif spec.kind == "attn":
+            n += d * self.n_heads * hd          # wq
+            n += 2 * d * self.n_kv_heads * hd   # wk, wv
+            n += self.n_heads * hd * d          # wo
+            if self.qk_norm:
+                n += 2 * hd
+        else:  # mamba2
+            di, N, H = self.d_inner, self.ssm_state, self.ssm_heads
+            n += d * (2 * di + 2 * N + H)   # in_proj (x,z,B,C,dt)
+            n += self.ssm_conv * (di + 2 * N)
+            n += 3 * H                       # A_log, D, dt_bias
+            n += di                          # gated norm
+            n += di * d                      # out_proj
+        if spec.ffn == "mlp":
+            n += d + 3 * d * self.d_ff
+            if self.post_norms:
+                n += d
+        elif spec.ffn == "moe":
+            n += d + d * self.moe_experts    # norm + router
+            n += self.moe_experts * 3 * d * self.moe_d_ff
+            n += 3 * d * self.moe_shared_d_ff
+            if self.post_norms:
+                n += d
+        return n
+
     def param_count(self) -> int:
         """Analytic parameter count (embedding + blocks + head)."""
         d, hd = self.d_model, self.head_dim
         total = self.vocab * d                     # embed
         if not self.tie_embeddings:
             total += d * self.vocab                # lm_head
-        per_block = 0
-        for spec in self.block:
-            per_block += d                          # pre-norm
-            if self.post_norms:
-                per_block += d
-            if spec.kind == "attn":
-                per_block += d * self.n_heads * hd          # wq
-                per_block += 2 * d * self.n_kv_heads * hd   # wk, wv
-                per_block += self.n_heads * hd * d          # wo
-                if self.qk_norm:
-                    per_block += 2 * hd
-            else:  # mamba2
-                di, N, H = self.d_inner, self.ssm_state, self.ssm_heads
-                per_block += d * (2 * di + 2 * N + H)   # in_proj (x,z,B,C,dt)
-                per_block += self.ssm_conv * (di + 2 * N)
-                per_block += 3 * H                       # A_log, D, dt_bias
-                per_block += di                          # gated norm
-                per_block += di * d                      # out_proj
-            if spec.ffn == "mlp":
-                per_block += d + 3 * d * self.d_ff
-                if self.post_norms:
-                    per_block += d
-            elif spec.ffn == "moe":
-                per_block += d + d * self.moe_experts    # norm + router
-                per_block += self.moe_experts * 3 * d * self.moe_d_ff
-                if self.post_norms:
-                    per_block += d
-        total += per_block * self.n_blocks
+        for _, specs, count in self.stacks:
+            total += count * sum(self._sublayer_params(spec) for spec in specs)
         total += d                                  # final norm
         if self.encoder_layers:
             enc = self.encoder_layers * (2 * d + d * self.n_heads * hd +
@@ -188,7 +241,7 @@ SHAPES: dict[str, ShapeConfig] = {
 def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     """Tiny same-family config for CPU smoke tests."""
     base = dict(
-        n_layers=len(cfg.block) * 2,
+        n_layers=cfg.n_dense_layers + len(cfg.block) * 2,
         d_model=64,
         n_heads=4,
         n_kv_heads=max(1, min(cfg.n_kv_heads, 2)),
@@ -207,6 +260,12 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         ssm_state=16 if cfg.ssm_state else 0,
         ssm_head_dim=16 if cfg.ssm_state else 64,
         mrope_sections=(4, 2, 2) if cfg.mrope_sections else (),
+        kv_lora_rank=32 if cfg.kv_lora_rank else 0,
+        qk_nope_head_dim=16 if cfg.kv_lora_rank else 0,
+        qk_rope_head_dim=8 if cfg.kv_lora_rank else 0,
+        v_head_dim=16 if cfg.kv_lora_rank else 0,
+        moe_shared_d_ff=64 if cfg.moe_shared_d_ff else 0,
+        yarn_original_max_pos=32 if cfg.yarn_factor else 0,
         dtype="float32",
     )
     base.update(overrides)

@@ -93,10 +93,12 @@ def _sum3(x, n: int):
 
 
 def _paged_kernel(lengths_ref, rows_ref, blks_ref, pages_ref, q_ref, *refs,
-                  page: int, ppb: int, softcap: float, sm_scale: float):
+                  page: int, ppb: int, kvh: int, softcap: float,
+                  sm_scale: float):
     del pages_ref                                      # read by the index maps
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, m_scr, l_scr, acc_scr = refs[2 * ppb:]
+    heads, hd = q_ref.shape[1:]
     step = pl.program_id(0)
     blk = blks_ref[step]
     length = lengths_ref[rows_ref[step]]
@@ -110,12 +112,11 @@ def _paged_kernel(lengths_ref, rows_ref, blks_ref, pages_ref, q_ref, *refs,
 
     @pl.when(start < length)
     def _attend():
-        _, group, kvh, hd = q_ref.shape
-        heads, cols = group * kvh, ppb * page * kvh
+        cols = ppb * page * kvh
         # (token, KV head) pairs as rows: (ppb * page * KV, hd)
         k = jnp.concatenate([r[0].reshape(page * kvh, hd) for r in k_refs])
         v = jnp.concatenate([r[0].reshape(page * kvh, hd) for r in v_refs])
-        q = q_ref[0].astype(jnp.float32).reshape(heads, hd) * sm_scale
+        q = q_ref[0].astype(jnp.float32) * sm_scale
         s = _sum3(jax.lax.dot_general(
             _split3(q), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32), heads)    # (heads, cols)
@@ -138,35 +139,40 @@ def _paged_kernel(lengths_ref, rows_ref, blks_ref, pages_ref, q_ref, *refs,
     @pl.when(start + ppb * page >= length)              # the row's last step
     def _finish():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("softcap", "interpret"))
+                   static_argnames=("softcap", "sm_scale", "interpret"))
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
-                    softcap: float = 0.0, interpret: bool = False):
-    """q: (B, H, hd); k/v_pages: (P, page, KV, hd);
-    page_table: (B, max_pages) int32; lengths: (B,) -> (B, H, hd).
+                    softcap: float = 0.0, sm_scale: float | None = None,
+                    interpret: bool = False):
+    """q: (B, H, hd); k/v_pages: (P, page, KV, hd), or (P, page, hd) for a
+    single KV head; page_table: (B, max_pages) int32; lengths: (B,) ->
+    (B, H, hd). Scores are scaled by ``sm_scale`` (default hd^-0.5).
 
     Table entries at or past a row's length are never read; a row of
     length 0 gives zeros."""
     b, h, hd = q.shape
-    _, page, kvh, _ = k_pages.shape
+    page = k_pages.shape[1]
+    kvh = k_pages.shape[2] if k_pages.ndim == 4 else 1
     max_pages = page_table.shape[1]
     group = h // kvh
     ppb = pages_per_block(page, kvh, hd, k_pages.dtype.itemsize, max_pages)
     n_steps, rows, blks, pages = _schedule(page_table, lengths, page=page,
                                            ppb=ppb)
     # heads as (group, KV): row r * KV + g of a step's queries is KV head g's
-    qg = q.reshape(b, kvh, group, hd).transpose(0, 2, 1, 3)
+    qg = q.reshape(b, kvh, group, hd).transpose(0, 2, 1, 3).reshape(b, h, hd)
 
-    kernel = functools.partial(_paged_kernel, page=page, ppb=ppb,
-                               softcap=softcap, sm_scale=hd ** -0.5)
-    row = pl.BlockSpec((1, group, kvh, hd),
-                       lambda s, lens, rows, blks, pages: (rows[s], 0, 0, 0))
-    slots = [pl.BlockSpec((1, page, kvh, hd),
+    kernel = functools.partial(
+        _paged_kernel, page=page, ppb=ppb, kvh=kvh, softcap=softcap,
+        sm_scale=hd ** -0.5 if sm_scale is None else sm_scale)
+    row = pl.BlockSpec((1, h, hd),
+                       lambda s, lens, rows, blks, pages: (rows[s], 0, 0))
+    trailing = (0,) * (k_pages.ndim - 1)
+    slots = [pl.BlockSpec((1, *k_pages.shape[1:]),
                           lambda s, lens, rows, blks, pages, j=j:
-                          (pages[s * ppb + j], 0, 0, 0))
+                          (pages[s * ppb + j], *trailing))
              for j in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                   # lengths and the schedule
@@ -174,14 +180,14 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
         in_specs=[row, *slots, *slots],
         out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((group * kvh, 1), jnp.float32),
-            pltpu.VMEM((group * kvh, 1), jnp.float32),
-            pltpu.VMEM((group * kvh, hd), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, group, kvh, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
         interpret=interpret,
     )(lengths, rows, blks, pages, qg, *[k_pages] * ppb, *[v_pages] * ppb)
-    return out.transpose(0, 2, 1, 3).reshape(b, h, hd)
+    return out.reshape(b, group, kvh, hd).transpose(0, 2, 1, 3).reshape(b, h, hd)

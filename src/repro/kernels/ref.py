@@ -34,13 +34,17 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, softcap=0.0,
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, lengths, *,
-                        softcap=0.0):
+                        softcap=0.0, sm_scale=None):
     """Decode attention over a paged pool.
 
-    q: (B, H, hd); k/v_pages: (P, page, KV, hd); page_table: (B, max_pages)
-    int32 (entries beyond the sequence are arbitrary); lengths: (B,).
+    q: (B, H, hd); k/v_pages: (P, page, KV, hd), or (P, page, hd) for one KV
+    head; page_table: (B, max_pages) int32 (entries beyond the sequence are
+    arbitrary); lengths: (B,). Scores are scaled by ``sm_scale`` (default
+    hd^-0.5).
     """
     b, h, hd = q.shape
+    if k_pages.ndim == 3:
+        k_pages, v_pages = k_pages[:, :, None], v_pages[:, :, None]
     n_pages, page, kvh, _ = k_pages.shape
     max_pages = page_table.shape[1]
     rep = h // kvh
@@ -48,7 +52,8 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, lengths, *,
     v_ctx = v_pages[page_table]
     k_ctx = k_ctx.reshape(b, max_pages * page, kvh, hd)
     v_ctx = v_ctx.reshape(b, max_pages * page, kvh, hd)
-    qf = (q.astype(jnp.float32) * hd ** -0.5).reshape(b, kvh, rep, hd)
+    scale = hd ** -0.5 if sm_scale is None else sm_scale
+    qf = (q.astype(jnp.float32) * scale).reshape(b, kvh, rep, hd)
     s = jnp.einsum("bgrd,bkgd->bgrk", qf, k_ctx.astype(jnp.float32))
     if softcap:
         s = softcap * jnp.tanh(s / softcap)

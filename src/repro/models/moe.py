@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.models.layers import init_mlp, mlp
 
 
 def init_moe(rng, cfg: ModelConfig) -> dict:
@@ -26,12 +27,16 @@ def init_moe(rng, cfg: ModelConfig) -> dict:
     k = jax.random.split(rng, 4)
     dt = jnp.dtype(cfg.dtype)
     s_in, s_out = d ** -0.5, f ** -0.5
-    return {
+    p = {
         "router": (jax.random.normal(k[0], (d, e)) * s_in).astype(jnp.float32),
         "w_gate": (jax.random.normal(k[1], (e, d, f)) * s_in).astype(dt),
         "w_up": (jax.random.normal(k[2], (e, d, f)) * s_in).astype(dt),
         "w_down": (jax.random.normal(k[3], (e, f, d)) * s_out).astype(dt),
     }
+    if cfg.moe_shared_d_ff:
+        p["shared"] = init_mlp(jax.random.fold_in(rng, 1), cfg,
+                               d_ff=cfg.moe_shared_d_ff)
+    return p
 
 
 def _capacity(tokens: int, cfg: ModelConfig, factor: float | None = None) -> int:
@@ -53,7 +58,8 @@ def moe_local(x, params, cfg: ModelConfig, *, ep_axis: str | None = None,
     logits = (xf.astype(jnp.float32) @ params["router"])          # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(probs, k_top)                          # (T, K)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_norm_topk:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
 
     # aux losses (switch-style load balance + router z-loss), averaged over
     # every axis that shards tokens so the scalar is truly replicated
@@ -109,10 +115,20 @@ def moe_local(x, params, cfg: ModelConfig, *, ep_axis: str | None = None,
     return out.reshape(b, s, d).astype(x.dtype), {"lb": lb_loss, "z": z_loss}
 
 
-def moe_ffn(x, params, cfg: ModelConfig, mesh=None,
-            dp_axes: tuple[str, ...] | None = None, ep_axis: str = "data",
-            tp_axis: str = "model"):
-    """MoE FFN with optional distribution. x: (B, S, d) global."""
+def moe_ffn(x, params, cfg: ModelConfig, mesh=None, **kw):
+    """MoE FFN with optional distribution. x: (B, S, d) global. Shared
+    experts (``params["shared"]``, one MLP) see every token and add to the
+    routed experts' output."""
+    routed = {k: v for k, v in params.items() if k != "shared"}
+    y, aux = _routed_ffn(x, routed, cfg, mesh, **kw)
+    if "shared" in params:
+        y = y + mlp(x, params["shared"], cfg.act)
+    return y, aux
+
+
+def _routed_ffn(x, params, cfg: ModelConfig, mesh=None,
+                dp_axes: tuple[str, ...] | None = None, ep_axis: str = "data",
+                tp_axis: str = "model"):
     if mesh is None or ep_axis not in mesh.shape:
         return moe_local(x, params, cfg)
     if dp_axes is None:

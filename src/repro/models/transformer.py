@@ -3,9 +3,10 @@
 One code path covers dense / MoE / SSM / hybrid / enc-dec / VLM:
 
 * the layer stack is ``n_blocks`` repetitions of the config's ``block``
-  pattern (1, 2 or 8 sublayers). Parameters for pattern position ``i`` are
-  stacked over blocks with leading dim ``n_blocks`` so the whole stack is a
-  single ``lax.scan`` — compact HLO at 80 layers and scan-level remat.
+  pattern (1, 2 or 8 sublayers), after the config's leading dense layers
+  (``cfg.stacks``). Parameters for pattern position ``i`` are stacked with
+  leading dim ``n_blocks`` so each stack is a single ``lax.scan`` — compact
+  HLO at 80 layers and scan-level remat.
 * train/prefill forward, single-token decode with KV / SSM-state caches
   (ring buffers for sliding-window layers), whisper cross-attention, and
   qwen2-vl M-RoPE with stubbed patch embeddings.
@@ -16,6 +17,7 @@ All functions are pure; distribution comes from the shardings pjit places on
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, NamedTuple
 
 import jax
@@ -76,6 +78,8 @@ def _init_sublayer(rng, spec: LayerSpec, cfg: ModelConfig, cross: bool) -> dict:
     p: dict = {"norm": L.init_rmsnorm(cfg.d_model)["scale"]}
     if spec.kind == "attn":
         p["attn"] = L.init_attention(ks[0], cfg)
+    elif spec.kind == "mla":
+        p["attn"] = L.init_mla(ks[0], cfg)
     else:
         p["attn"] = init_mamba(ks[0], cfg)
     if cfg.post_norms:
@@ -98,8 +102,7 @@ def _init_sublayer(rng, spec: LayerSpec, cfg: ModelConfig, cross: bool) -> dict:
 
 def init_params(rng, cfg: ModelConfig) -> dict:
     """Returns the full parameter pytree. blocks[i] leaves have leading
-    dim n_blocks (stacked for lax.scan)."""
-    n_blocks = cfg.n_blocks
+    dim n_blocks (stacked for lax.scan); dense[0] leaves, n_dense_layers."""
     k_embed, k_blocks, k_head, k_enc = jax.random.split(rng, 4)
     dt = jnp.dtype(cfg.dtype)
     params: dict = {
@@ -108,11 +111,15 @@ def init_params(rng, cfg: ModelConfig) -> dict:
         "final_norm": L.init_rmsnorm(cfg.d_model)["scale"],
     }
     cross = cfg.encoder_layers > 0
-    blocks = []
-    for i, spec in enumerate(cfg.block):
-        keys = jax.random.split(jax.random.fold_in(k_blocks, i), n_blocks)
-        blocks.append(jax.vmap(lambda k: _init_sublayer(k, spec, cfg, cross))(keys))
-    params["blocks"] = tuple(blocks)
+    for name, specs, count in cfg.stacks:
+        first = 0 if name == "blocks" else 1000       # a key stream per stack
+        stacked = []
+        for i, spec in enumerate(specs):
+            keys = jax.random.split(jax.random.fold_in(k_blocks, first + i),
+                                    count)
+            stacked.append(jax.vmap(
+                lambda k: _init_sublayer(k, spec, cfg, cross))(keys))
+        params[name] = tuple(stacked)
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(k_head, (cfg.d_model, cfg.vocab))
                              * cfg.d_model ** -0.5).astype(dt)
@@ -167,6 +174,8 @@ def _apply_sublayer(x, p, spec: LayerSpec, cfg: ModelConfig, *, positions,
     if spec.kind == "attn":
         h = L.multihead_attention(h, p["attn"], cfg, positions=positions,
                                   window=spec.window, causal=True)
+    elif spec.kind == "mla":
+        h = L.mla_attention(h, p["attn"], cfg, positions=positions)
     else:
         h = mamba_chunked(h, p["attn"], cfg)
     if cfg.post_norms:
@@ -228,29 +237,31 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
     enc_out = (_encoder_forward(params, enc_frames, cfg, mesh)
                if cfg.encoder_layers else None)
 
-    def block_body(carry, block_params):
-        xc, aux = carry
-        xc = (_constrain_seq(xc, mesh, cfg) if cfg.seq_parallel
-              else _constrain_batch(xc, mesh))
-        for i, spec in enumerate(cfg.block):
-            xc, aux = _apply_sublayer(xc, block_params[i], spec, cfg,
-                                      positions=positions, mesh=mesh,
-                                      enc_out=enc_out, aux=aux)
-        return (xc, aux), None
+    def make_body(specs):
+        def block_body(carry, block_params):
+            xc, aux = carry
+            xc = (_constrain_seq(xc, mesh, cfg) if cfg.seq_parallel
+                  else _constrain_batch(xc, mesh))
+            for i, spec in enumerate(specs):
+                xc, aux = _apply_sublayer(xc, block_params[i], spec, cfg,
+                                          positions=positions, mesh=mesh,
+                                          enc_out=enc_out, aux=aux)
+            return (xc, aux), None
 
-    if remat == "dots":
-        # plenty of HBM headroom in most cells: save matmul outputs and
-        # recompute only elementwise chains in bwd (SSPerf: removes the
-        # full-block fwd recompute)
-        body = jax.checkpoint(
-            block_body, policy=jax.checkpoint_policies.dots_saveable)
-    elif remat:
-        body = jax.checkpoint(block_body)
-    else:
-        body = block_body
-    aux0 = {"lb": jnp.zeros((), jnp.float32), "z": jnp.zeros((), jnp.float32)}
+        if remat == "dots":
+            # plenty of HBM headroom in most cells: save matmul outputs and
+            # recompute only elementwise chains in bwd (SSPerf: removes the
+            # full-block fwd recompute)
+            return jax.checkpoint(
+                block_body, policy=jax.checkpoint_policies.dots_saveable)
+        if remat:
+            return jax.checkpoint(block_body)
+        return block_body
+
+    aux = {"lb": jnp.zeros((), jnp.float32), "z": jnp.zeros((), jnp.float32)}
     x = _constrain_batch(x, mesh)
-    (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
+    for name, specs, _ in cfg.stacks:
+        (x, aux), _ = jax.lax.scan(make_body(specs), (x, aux), params[name])
     return L.rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
 
 
@@ -302,7 +313,7 @@ def lm_loss(params, cfg: ModelConfig, hidden, labels, mask=None,
 
 class DecodeCache(NamedTuple):
     lengths: jax.Array       # (B,) tokens already in cache
-    layers: tuple            # per pattern position: dict of stacked leaves
+    layers: tuple            # per position of cfg.layer_specs: stacked leaves
     cross: Any = None        # whisper: {"k","v"}: (n_layers, B, enc_seq, KV, hd)
 
 
@@ -310,22 +321,32 @@ def _attn_cache_cap(spec: LayerSpec, max_seq: int) -> int:
     return min(spec.window, max_seq) if spec.window else max_seq
 
 
+def stack_positions(cfg: ModelConfig):
+    """(params key, pattern, repeats, first position in cfg.layer_specs)."""
+    start = 0
+    for name, specs, count in cfg.stacks:
+        yield name, specs, count, start
+        start += len(specs)
+
+
 def init_decode_cache(cfg: ModelConfig, batch: int, max_seq: int) -> DecodeCache:
     dt = jnp.dtype(cfg.dtype)
-    nb = cfg.n_blocks
     layer_caches = []
-    for spec in cfg.block:
-        if spec.kind == "attn":
-            cap = _attn_cache_cap(spec, max_seq)
-            layer_caches.append({
-                "k": jnp.zeros((nb, batch, cap, cfg.n_kv_heads, cfg.head_dim), dt),
-                "v": jnp.zeros((nb, batch, cap, cfg.n_kv_heads, cfg.head_dim), dt),
-                "kpos": jnp.full((nb, batch, cap), KPOS_INVALID, jnp.int32),
-            })
-        else:
-            st = init_mamba_state(batch, cfg)
-            layer_caches.append(jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], (nb, *a.shape)), st))
+    for _, specs, nb in cfg.stacks:
+        for spec in specs:
+            if spec.kind in ("attn", "mla"):
+                cap = _attn_cache_cap(spec, max_seq)
+                if spec.kind == "attn":
+                    shape = (nb, batch, cap, cfg.n_kv_heads, cfg.head_dim)
+                    entry = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+                else:
+                    entry = {"kv": jnp.zeros((nb, batch, cap, cfg.mla_cache_width), dt)}
+                entry["kpos"] = jnp.full((nb, batch, cap), KPOS_INVALID, jnp.int32)
+                layer_caches.append(entry)
+            else:
+                st = init_mamba_state(batch, cfg)
+                layer_caches.append(jax.tree.map(
+                    lambda a: jnp.broadcast_to(a[None], (nb, *a.shape)), st))
     cross = None
     if cfg.encoder_layers:
         cross = {
@@ -385,6 +406,22 @@ def _decode_attn_sublayer(x, p, spec: LayerSpec, cfg: ModelConfig, cache,
     return out @ p["wo"], {"k": k_cache, "v": v_cache, "kpos": kpos}
 
 
+def _decode_mla_sublayer(x, p, cfg: ModelConfig, cache, lengths, positions):
+    """Absorbed latent attention over a ring cache {"kv","kpos"}."""
+    b = x.shape[0]
+    q = L.mla_absorbed_query(x, p, cfg, positions)          # (B, H, W)
+    lat = L.mla_latent(x, p, cfg, positions)                # (B, 1, W)
+    slot = lengths % cache["kv"].shape[1]
+    bidx = jnp.arange(b)
+    kv = cache["kv"].at[bidx, slot].set(lat[:, 0])
+    kpos = cache["kpos"].at[bidx, slot].set(lengths)
+    out = L.decode_attention(q[:, None], kv[:, :, None], kv[:, :, None],
+                             lengths=lengths + 1, softcap=cfg.attn_softcap,
+                             kpos=kpos, sm_scale=L.mla_softmax_scale(cfg))
+    out = out.reshape(b, cfg.n_heads, cfg.mla_cache_width)
+    return L.mla_absorbed_output(out, p, cfg), {"kv": kv, "kpos": kpos}
+
+
 def decode_step(params, tokens, cache: DecodeCache, cfg: ModelConfig, *,
                 positions=None, mesh=None):
     """tokens: (B, 1) -> (logits (B,1,V), new cache)."""
@@ -401,17 +438,20 @@ def decode_step(params, tokens, cache: DecodeCache, cfg: ModelConfig, *,
     if cfg.rope_theta == 0.0 and cfg.encoder_layers:
         x = x + L.sinusoidal_positions(lengths[:, None], cfg.d_model).astype(x.dtype)
 
-    def block_body(xc, scanned):
+    def block_body(specs, xc, scanned):
         block_params, layer_cache = scanned[0], scanned[1]
-        cross_kv = scanned[2] if cfg.encoder_layers else None
+        cross_kv = scanned[2] if len(scanned) > 2 else None
         xc = _constrain_batch(xc, mesh)
         new_caches = []
-        for i, spec in enumerate(cfg.block):
+        for i, spec in enumerate(specs):
             p = block_params[i]
             h = L.rmsnorm(xc, p["norm"], cfg.norm_eps)
             if spec.kind == "attn":
                 h, nc = _decode_attn_sublayer(h, p["attn"], spec, cfg,
                                               layer_cache[i], lengths, positions)
+            elif spec.kind == "mla":
+                h, nc = _decode_mla_sublayer(h, p["attn"], cfg, layer_cache[i],
+                                             lengths, positions)
             else:
                 h, nc = mamba_decode_step(h, layer_cache[i], p["attn"], cfg)
             if cfg.post_norms:
@@ -434,10 +474,13 @@ def decode_step(params, tokens, cache: DecodeCache, cfg: ModelConfig, *,
                 xc = xc + h
         return xc, tuple(new_caches)
 
-    xs = (params["blocks"], cache.layers)
-    if cfg.encoder_layers:
-        xs = xs + (cache.cross,)
-    x, new_layers = jax.lax.scan(block_body, x, xs)
+    new_layers = ()
+    for name, specs, _, start in stack_positions(cfg):
+        xs = (params[name], cache.layers[start:start + len(specs)])
+        if cfg.encoder_layers:
+            xs = xs + (cache.cross,)
+        x, new = jax.lax.scan(partial(block_body, specs), x, xs)
+        new_layers += new
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_fn(params, x, cfg)
     new_cache = DecodeCache(lengths=lengths + 1, layers=new_layers,
@@ -477,20 +520,25 @@ def prefill(params, tokens, cfg: ModelConfig, max_seq: int, *,
                if cfg.encoder_layers else None)
     aux0 = {"lb": jnp.zeros((), jnp.float32), "z": jnp.zeros((), jnp.float32)}
 
-    def block_body(carry, block_params):
+    def block_body(specs, carry, block_params):
         xc, aux = carry
         xc = _constrain_batch(xc, mesh)
         caches = []
-        for i, spec in enumerate(cfg.block):
+        for i, spec in enumerate(specs):
             p = block_params[i]
-            if spec.kind == "attn":
+            if spec.kind in ("attn", "mla"):
                 hpre = L.rmsnorm(xc, p["norm"], cfg.norm_eps)
-                k, v = L.project_kv(hpre, p["attn"], cfg, positions)
                 cap = _attn_cache_cap(spec, max_seq)
                 pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
                                        (b, s))
-                caches.append({"k": _ring(k, cap, 0), "v": _ring(v, cap, 0),
-                               "kpos": _ring(pos, cap, KPOS_INVALID)})
+                if spec.kind == "attn":
+                    k, v = L.project_kv(hpre, p["attn"], cfg, positions)
+                    entry = {"k": _ring(k, cap, 0), "v": _ring(v, cap, 0)}
+                else:
+                    lat = L.mla_latent(hpre, p["attn"], cfg, positions)
+                    entry = {"kv": _ring(lat, cap, 0)}
+                entry["kpos"] = _ring(pos, cap, KPOS_INVALID)
+                caches.append(entry)
                 xc, aux = _apply_sublayer(xc, p, spec, cfg, positions=positions,
                                           mesh=mesh, enc_out=enc_out, aux=aux)
             else:
@@ -521,8 +569,12 @@ def prefill(params, tokens, cfg: ModelConfig, max_seq: int, *,
                     xc = xc + hh
         return (xc, aux), tuple(caches)
 
-    (x, _aux), layer_caches = jax.lax.scan(block_body, (x, aux0), params["blocks"])
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    carry, layer_caches = (x, aux0), ()
+    for name, specs, _ in cfg.stacks:
+        carry, caches = jax.lax.scan(partial(block_body, specs), carry,
+                                     params[name])
+        layer_caches += caches
+    x = L.rmsnorm(carry[0], params["final_norm"], cfg.norm_eps)
     last = x[:, -1:, :]
     logits = logits_fn(params, last, cfg)
     cross = (encode_cross_kv(params, enc_frames, cfg, mesh)

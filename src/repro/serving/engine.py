@@ -90,8 +90,9 @@ class ServeEngine:
         self.step_fn = make_paged_decode_step(cfg, page_size=page_size,
                                               use_kernel=use_kernel,
                                               interpret=interpret)
-        self._attn_positions = [i for i, s in enumerate(cfg.block)
-                                if s.kind == "attn"]
+        # pool positions whose leaves are paged (axis 1 = page id)
+        self._paged_positions = [i for i, s in enumerate(cfg.layer_specs)
+                                 if s.kind in ("attn", "mla")]
         self._reqs: dict[int, Request] = {}
         self._waiting: list[int] = []
         self._rows: list[Optional[int]] = [None] * max_batch
@@ -106,6 +107,10 @@ class ServeEngine:
         self.unflushed_at_preempt = 0    # full pages the flusher had not cleaned
         self.attn_pages_read = 0         # live pages the paged kernel attended
         self.attn_pages_spanned = 0      # table entries of the decoding rows
+        self.decode_rows = 0             # decoding rows, summed over steps
+        self.decode_context_tokens = 0   # their lengths + 1, summed
+        self.prefill_tokens = 0          # prompt tokens prefilled
+        self.prefill_attn_pairs = 0      # causal (query, key) pairs prefilled
         self._steps = 0
 
     # ------------------------------------------------------------- tags
@@ -114,14 +119,14 @@ class ServeEngine:
 
     # -------------------------------------------------- device<->host copies
     def _copy_out(self, tag: int, page_id: int | None = None):
+        """One page of every paged leaf, as host arrays: [{leaf: array}]
+        in the order of ``_paged_positions``."""
         pid = self.pool.alloc.where.get(tag) if page_id is None else page_id
         if pid is None:
             return None
-        ks, vs = [], []
-        for pos in self._attn_positions:
-            ks.append(np.asarray(self.pools[pos]["k"][:, pid]))
-            vs.append(np.asarray(self.pools[pos]["v"][:, pid]))
-        return (ks, vs)
+        return [{name: np.asarray(leaf[:, pid])
+                 for name, leaf in self.pools[pos].items()}
+                for pos in self._paged_positions]
 
     def _copy_in(self, tag: int, data) -> None:
         # serialized: concurrent fetch workers would lose each other's
@@ -130,13 +135,11 @@ class ServeEngine:
             pid = self.pool.alloc.where.get(tag)
             if pid is None:
                 return
-            ks, vs = data
             new_pools = list(self.pools)
-            for j, pos in enumerate(self._attn_positions):
+            for pos, page in zip(self._paged_positions, data):
                 new_pools[pos] = {
-                    "k": self.pools[pos]["k"].at[:, pid].set(jnp.asarray(ks[j])),
-                    "v": self.pools[pos]["v"].at[:, pid].set(jnp.asarray(vs[j])),
-                }
+                    name: leaf.at[:, pid].set(jnp.asarray(page[name]))
+                    for name, leaf in self.pools[pos].items()}
             self.pools = tuple(new_pools)
 
     # ------------------------------------------------------------ public
@@ -204,9 +207,7 @@ class ServeEngine:
         self.pool.alloc.free(req.pages)
         # scan by rid: host-tier copies of pages evicted while preempted are
         # no longer listed in req.pages but must not leak
-        for tag in [t for t in self.pool.host_tier
-                    if t // MAX_PAGES_PER_SEQ == req.rid]:
-            self.pool.host_tier.pop(tag, None)
+        self.pool.drop_host_copies(lambda t: t // MAX_PAGES_PER_SEQ == req.rid)
         req.pages.clear()
 
     # ------------------------------------------------------------- admit
@@ -266,19 +267,19 @@ class ServeEngine:
                 logits, cache = self._prefill(self.params, toks, max_seq=pad)
             with TraceAnnotation("serve.prefill.page_write"):
                 new_pools = list(self.pools)
-                for i, spec in enumerate(cfg.block):
+                for i, spec in enumerate(cfg.layer_specs):
                     lc = cache.layers[i]
-                    if spec.kind == "attn":
-                        k = lc["k"][:, 0]                  # (nb, pad, kvh, hd)
-                        v = lc["v"][:, 0]
-                        kp, vp = new_pools[i]["k"], new_pools[i]["v"]
-                        for tag in req.pages:
-                            pi = tag % MAX_PAGES_PER_SEQ  # page index from tag
-                            pid = self.pool.alloc.where[tag]
-                            sl = slice(pi * self.page, (pi + 1) * self.page)
-                            kp = kp.at[:, pid].set(k[:, sl])
-                            vp = vp.at[:, pid].set(v[:, sl])
-                        new_pools[i] = {"k": kp, "v": vp}
+                    if i in self._paged_positions:
+                        written = {}
+                        for name, leaf in new_pools[i].items():
+                            new = lc[name][:, 0]       # (nb, pad, ...)
+                            for tag in req.pages:
+                                pi = tag % MAX_PAGES_PER_SEQ  # page index
+                                pid = self.pool.alloc.where[tag]
+                                sl = slice(pi * self.page, (pi + 1) * self.page)
+                                leaf = leaf.at[:, pid].set(new[:, sl])
+                            written[name] = leaf
+                        new_pools[i] = written
                     else:
                         st = new_pools[i]
                         new_pools[i] = jax.tree.map(
@@ -286,6 +287,8 @@ class ServeEngine:
                             st, {k: lc[k] for k in st})
                 # NOTE: prefill caches beyond ``s`` are zeros — masked by lengths.
                 self.pools = tuple(new_pools)
+                self.prefill_tokens += s
+                self.prefill_attn_pairs += s * (s + 1) // 2
                 self._lengths[row] = s
                 self._tables[row, :] = self.scratch_page
                 for tag in req.pages:
@@ -351,8 +354,11 @@ class ServeEngine:
             active = np.zeros(self.max_batch, bool)
             active[active_rows] = True
             self.attn_pages_spanned += len(active_rows) * self.max_pages
+            self.decode_rows += len(active_rows)
+            # the step attends over lengths + 1 tokens
+            self.decode_context_tokens += int(np.sum(
+                self._lengths[active_rows] + 1))
             if self.use_kernel:
-                # the step attends over lengths + 1 tokens
                 self.attn_pages_read += int(np.sum(
                     self._lengths[active_rows] // self.page + 1))
             logits, self.pools = self.step_fn(
@@ -416,6 +422,10 @@ class ServeEngine:
             "unflushed_at_preempt": self.unflushed_at_preempt,
             "attn_pages_read": self.attn_pages_read,
             "attn_pages_spanned": self.attn_pages_spanned,
+            "decode_rows": self.decode_rows,
+            "decode_context_tokens": self.decode_context_tokens,
+            "prefill_tokens": self.prefill_tokens,
+            "prefill_attn_pairs": self.prefill_attn_pairs,
         }
 
     def close(self):

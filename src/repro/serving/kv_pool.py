@@ -316,6 +316,14 @@ class PagedKVPool:
                 self.host_tier[tag] = data
                 self.alloc.stats.offloads += 1
 
+    def drop_host_copies(self, owned: Callable[[int], bool]) -> None:
+        """Drops the host tier's copies of the pages whose tags ``owned``
+        accepts. Under the lock: the IO workers insert into the tier while
+        the scan runs."""
+        with self._lock:
+            for tag in [t for t in self.host_tier if owned(t)]:
+                del self.host_tier[tag]
+
     def mark_redirtied(self, tag: int) -> None:
         """New tokens written into a page that had a host copy: the copy is
         stale (paper §3.3.2 rule (ii) inverse) — drop it, re-dirty."""

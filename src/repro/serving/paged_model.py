@@ -2,12 +2,15 @@
 
 Mirrors ``models/transformer.decode_step`` but attention layers read/write
 the shared HBM page pool through a per-sequence page table instead of dense
-per-sequence ring buffers. Mamba/conv states stay per-row ("pinned pages",
-DESIGN.md §5). The whole step jits; the pool arrays are donated so page
-writes are in-place on device.
+per-sequence ring buffers. A multi-head attention layer's pool holds K and
+V pages, a latent attention (MLA) layer's one pool of cache entries
+(``cfg.mla_cache_width`` lanes a token), which decode reads as keys and as
+values in the absorbed form. Mamba/conv states stay per-row ("pinned
+pages"). The whole step jits; the pools are not donated (see below).
 """
 from __future__ import annotations
 
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -23,18 +26,22 @@ from repro.kernels.ref import paged_attention_ref
 
 def init_pools(cfg: ModelConfig, *, num_pages: int, page_size: int,
                max_batch: int):
-    """Device arrays: per block position, stacked over n_blocks."""
+    """Device arrays: per position of ``cfg.layer_specs``, stacked over its
+    stack's layers. A paged leaf's axis 1 is the page id."""
     dt = jnp.dtype(cfg.dtype)
-    nb = cfg.n_blocks
     pools = []
-    for spec in cfg.block:
-        if spec.kind == "attn":
-            shape = (nb, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-            pools.append({"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)})
-        else:
-            st = init_mamba_state(max_batch, cfg)
-            pools.append(jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], (nb, *a.shape)).copy(), st))
+    for _, specs, nb in cfg.stacks:
+        for spec in specs:
+            if spec.kind == "attn":
+                shape = (nb, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+                pools.append({"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)})
+            elif spec.kind == "mla":
+                shape = (nb, num_pages, page_size, cfg.mla_cache_width)
+                pools.append({"kv": jnp.zeros(shape, dt)})
+            else:
+                st = init_mamba_state(max_batch, cfg)
+                pools.append(jax.tree.map(
+                    lambda a: jnp.broadcast_to(a[None], (nb, *a.shape)).copy(), st))
     return tuple(pools)
 
 
@@ -86,6 +93,25 @@ def make_paged_decode_step(cfg: ModelConfig, *, page_size: int,
         out = out.reshape(b, 1, h * hd)
         return out @ p["wo"], {"k": k_pool, "v": v_pool}
 
+    def mla_sublayer(x, p, layer_pool, lengths, page_table, active, positions):
+        """Absorbed latent attention: the query through W_UK scores the
+        pages' entries (latent and roped key); the weighted latent goes
+        through W_UV. The pool is the kernel's K and V both."""
+        b = x.shape[0]
+        q = L.mla_absorbed_query(x, p, cfg, positions)          # (B, H, W)
+        lat = L.mla_latent(x, p, cfg, positions)[:, 0]          # (B, W)
+        pids = page_table[jnp.arange(b), lengths // page_size]
+        offs = lengths % page_size
+        pool = layer_pool["kv"]
+        pool = pool.at[pids, offs].set(
+            jnp.where(active[:, None], lat, pool[pids, offs]))
+        attn_lengths = jnp.where(active, lengths + 1, 0)
+        attend = partial(paged_attention, interpret=interpret) if use_kernel \
+            else paged_attention_ref
+        out = attend(q, pool, pool, page_table, attn_lengths,
+                     softcap=cfg.attn_softcap, sm_scale=L.mla_softmax_scale(cfg))
+        return L.mla_absorbed_output(out, p, cfg), {"kv": pool}
+
     def step(params, pools, tokens, lengths, page_table, active):
         b = tokens.shape[0]
         positions = lengths[:, None]
@@ -95,16 +121,16 @@ def make_paged_decode_step(cfg: ModelConfig, *, page_size: int,
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
 
-        def block_body(xc, scanned):
+        def block_body(specs, xc, scanned):
             block_params, layer_pools = scanned
             new_pools = []
-            for i, spec in enumerate(cfg.block):
+            for i, spec in enumerate(specs):
                 p = block_params[i]
                 h = L.rmsnorm(xc, p["norm"], cfg.norm_eps)
-                if spec.kind == "attn":
-                    h, np_ = attn_sublayer(h, p["attn"], layer_pools[i],
-                                           lengths, page_table, active,
-                                           positions)
+                if spec.kind in ("attn", "mla"):
+                    sub = attn_sublayer if spec.kind == "attn" else mla_sublayer
+                    h, np_ = sub(h, p["attn"], layer_pools[i], lengths,
+                                 page_table, active, positions)
                 else:
                     h, st = mamba_decode_step(h, layer_pools[i], p["attn"], cfg)
                     np_ = jax.tree.map(
@@ -127,7 +153,11 @@ def make_paged_decode_step(cfg: ModelConfig, *, page_size: int,
                     xc = xc + hh
             return xc, tuple(new_pools)
 
-        x, new_pools = jax.lax.scan(block_body, x, (params["blocks"], pools))
+        new_pools = ()
+        for name, specs, _, start in T.stack_positions(cfg):
+            x, new = jax.lax.scan(partial(block_body, specs), x,
+                                  (params[name], pools[start:start + len(specs)]))
+            new_pools += new
         x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
         logits = T.logits_fn(params, x, cfg)
         return logits, new_pools

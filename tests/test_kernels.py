@@ -194,3 +194,27 @@ def test_flush_scores_matches_host_policies():
     for i in range(50):
         want = policies.flush_scores(hits[i], int(clock[i]), valid=valid[i])
         np.testing.assert_array_equal(out[i], want)
+
+
+def test_paged_attention_latent_pool():
+    """One KV head shared by 16 query heads, the pool a 3-D array of cache
+    entries passed as both K and V (latent attention's absorbed decode),
+    with its own softmax scale: as the reference, and as the attention
+    written out."""
+    b, h, w, page, maxp, pool = 3, 16, 256, 16, 40, 160
+    q = _rand((b, h, w), jnp.float32)
+    kv = _rand((pool, page, w), jnp.bfloat16)
+    table = jnp.asarray(RNG.integers(0, pool, size=(b, maxp)), jnp.int32)
+    lengths = jnp.asarray([1, 531, maxp * page], jnp.int32)
+    out = paged_attention(q, kv, kv, table, lengths, sm_scale=0.11472,
+                          interpret=True)
+    want = ref.paged_attention_ref(q, kv, kv, table, lengths, sm_scale=0.11472)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    ctx = np.asarray(kv, np.float32)[np.asarray(table)].reshape(b, -1, w)
+    for i, n in enumerate(np.asarray(lengths)):
+        s = 0.11472 * np.asarray(q[i]) @ ctx[i, :n].T
+        p = np.exp(s - s.max(-1, keepdims=True))
+        np.testing.assert_allclose(np.asarray(out[i]),
+                                   (p / p.sum(-1, keepdims=True)) @ ctx[i, :n],
+                                   atol=2e-5, rtol=2e-4)

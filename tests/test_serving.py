@@ -194,3 +194,33 @@ def test_allocator_never_evicts_pinned():
             pid, ev, _ = a.alloc(tt)
             assert pid is not None and ev == tags[0]
             break
+
+
+def test_host_copies_dropped_while_workers_offload():
+    """A finished request's host-tier copies are dropped while the IO
+    workers go on inserting offloaded pages into the tier (with 160 rows an
+    unlocked scan of the tier failed on the chip: the dict changed size
+    during the iteration)."""
+    import threading
+    from repro.serving.kv_pool import PagedKVPool
+    pool = PagedKVPool(2, 2, copy_out=lambda tag: (), copy_in=lambda t, d: None)
+    done = threading.Event()
+
+    def offload_many():
+        for tag in range(200_000):
+            pool._do_io(tag % 2, {"op": "offload", "tag": tag})
+        done.set()
+
+    worker = threading.Thread(target=offload_many)
+    worker.start()
+    try:
+        # bounded: the lock is not fair, and the worker may wait on it
+        for _ in range(3000):
+            if done.is_set():
+                break
+            pool.drop_host_copies(lambda t: t % 2 == 0)
+    finally:
+        worker.join()
+        pool.close()
+    pool.drop_host_copies(lambda t: t % 2 == 0)
+    assert pool.host_tier and all(t % 2 for t in pool.host_tier)

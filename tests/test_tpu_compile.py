@@ -138,3 +138,36 @@ def test_olmoe_paged_decode_step_reads_pages_in_the_kernel(one_chip):
     assert not gathered, f"the table is gathered: {gathered[:3]}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 0.5e9, f"temporaries {temp / 1e9:.2f} GB"
+
+
+def test_deepseek_latent_decode_step_reads_pages_in_the_kernel(one_chip):
+    """The dsv2lite-serve-longgen decode step with the kernel: DeepSeek-V2-
+    Lite at published widths, the dense layer and 4 MoE layers, 160 rows x
+    448 pages, 23,001 pages of 16 tokens. Attention over the latent pool is
+    one custom call a stack; no gathered (B x max_pages) copy of the table
+    appears, and the temporaries stay under 0.6 GB: one layer's slice of
+    the pool (0.47 GB), as OLMoE's 0.27 GB are one layer's K and V."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=5)
+    batch, page, max_pages, pages = 160, 16, 448, 23001
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(functools.partial(init_params, cfg=cfg),
+                                    jax.random.PRNGKey(0)))
+    pools = on_chip(jax.eval_shape(functools.partial(
+        init_pools, cfg, num_pages=pages, page_size=page, max_batch=batch)))
+    step = make_paged_decode_step(cfg, page_size=page, use_kernel=True)
+    compiled = step.lower(params, pools,
+                          _spec(one_chip, (batch, 1), jnp.int32),
+                          _spec(one_chip, (batch,), jnp.int32),
+                          _spec(one_chip, (batch, max_pages), jnp.int32),
+                          _spec(one_chip, (batch,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2        # the dense stack, the MoE stack
+    gathered = re.findall(rf"(?:bf16|f32)\[{batch * max_pages},", text)
+    assert not gathered, f"the table is gathered: {gathered[:3]}"
+    mem = compiled.memory_analysis()
+    print(f"arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, outputs "
+          f"{mem.output_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < 0.6e9, \
+        f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB"
