@@ -15,6 +15,16 @@ This is the paper's cache+flusher+queues stack serving as a first-class
 inference feature; stats expose exactly the quantities the paper reports
 (extra writeback, stall counts, queue discards).
 
+One decode step stays in flight ahead of the host's bookkeeping: a step
+dispatches step N before it reads step N-1's tokens, so the host books N-1
+while the device decodes N. Nothing the host decides for N depends on a
+token value: which rows decode, the page each writes and when a row ends
+(``max_new`` is a count) are known at dispatch, and N's input tokens are
+N-1's argmax, which stays on the device. What needs the values (``out``, the
+GClock touch of the pages read, freeing finished rows) runs after the next
+dispatch. Before a preemption, and at the end of ``run()`` and in
+``close()``, the step in flight is read and booked first (a drain).
+
 Each phase of a step and of an admission opens a ``jax.profiler`` span
 named ``serve.*`` (``serve.step`` > ``serve.admit`` > ``serve.prefill`` ...,
 ``serve.grow``, ``serve.dispatch``, ``serve.sync``, ``serve.bookkeep``,
@@ -54,6 +64,14 @@ class Request:
     pages: list[int] = field(default_factory=list)     # tags, in order
 
 
+@dataclass
+class _InFlight:
+    """A dispatched decode step whose tokens the host has not read."""
+    tokens: jax.Array                 # (max_batch,) int32, on the device
+    rows: list[tuple[int, int]]       # (row, rid) of each decoding row
+    finishing: list[int]              # rids whose last token it computes
+
+
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
                  page_size: int = 16, num_sets: int = 32, set_size: int = 4,
@@ -87,6 +105,13 @@ class ServeEngine:
         # one compile per (prompt length, padded length), not one per call;
         # the module is named jit_prefill
         self._prefill = jax.jit(prefill, static_argnames="max_seq")
+
+        def next_tokens(carried, host, carry):
+            # a row that goes on decoding takes the token of the step in
+            # flight; a row admitted or resumed since takes the host's
+            return jnp.where(carry, carried, host)[:, None]
+
+        self._next_tokens = jax.jit(next_tokens)
         self.step_fn = make_paged_decode_step(cfg, page_size=page_size,
                                               use_kernel=use_kernel,
                                               interpret=interpret)
@@ -101,6 +126,7 @@ class ServeEngine:
         self._tables = np.full((max_batch, max_pages), self.scratch_page,
                                np.int32)
         self._last_tok = np.zeros(max_batch, np.int32)
+        self._inflight: Optional[_InFlight] = None
         self._pools_lock = __import__("threading").Lock()
         self.preemptions = 0
         self.blocking_offloads = 0
@@ -111,6 +137,8 @@ class ServeEngine:
         self.decode_context_tokens = 0   # their lengths + 1, summed
         self.prefill_tokens = 0          # prompt tokens prefilled
         self.prefill_attn_pairs = 0      # causal (query, key) pairs prefilled
+        self.overlapped_steps = 0        # dispatched with the last step unread
+        self.drains = 0                  # steps in flight read early
         self._steps = 0
 
     # ------------------------------------------------------------- tags
@@ -179,13 +207,17 @@ class ServeEngine:
             self._preempt(victim)
 
     def _pick_victim(self, exclude: int) -> Optional[Request]:
+        # a request released by the step in flight holds no row
         active = [r for r in self._reqs.values()
-                  if r.state == "active" and r.rid != exclude]
+                  if r.state == "active" and r.row >= 0 and r.rid != exclude]
         if not active:
             return None
         return max(active, key=lambda r: r.rid)        # youngest first (LIFO)
 
     def _preempt(self, req: Request) -> None:
+        # book the step in flight first: the victim's tokens, pages and
+        # dirty bits must be current when its pages go to the host tier
+        self._drain()
         with TraceAnnotation("serve.preempt", rid=req.rid):
             self.preemptions += 1
             # partial (dirty, non-full) pages + any un-offloaded full pages
@@ -202,6 +234,14 @@ class ServeEngine:
             self._tables[req.row, :] = self.scratch_page
             req.state = "preempted"
             req.row = -1
+
+    def _release(self, req: Request) -> None:
+        """Gives a finished request's row and pages back (once)."""
+        if req.row >= 0:
+            self._rows[req.row] = None
+            self._tables[req.row, :] = self.scratch_page
+            req.row = -1
+            self._free(req)
 
     def _free(self, req: Request) -> None:
         self.pool.alloc.free(req.pages)
@@ -326,13 +366,15 @@ class ServeEngine:
             self._step()
 
     def _step(self) -> None:
+        # rows whose last token the step in flight computes are free now
+        if self._inflight is not None:
+            for rid in self._inflight.finishing:
+                self._release(self._reqs[rid])
         with TraceAnnotation("serve.admit"):
             for rid in list(self._waiting):
                 if self._admit(rid):
                     self._waiting.remove(rid)
         active_rows = [i for i, r in enumerate(self._rows) if r is not None]
-        if not active_rows:
-            return
         # ensure a page exists for the next position of every active row
         with TraceAnnotation("serve.grow"):
             for i in active_rows:
@@ -348,64 +390,104 @@ class ServeEngine:
                         continue
                     self._tables[i, pi] = self.pool.alloc.where[tag]
         active_rows = [i for i, r in enumerate(self._rows) if r is not None]
-        if not active_rows:
-            return
-        with TraceAnnotation("serve.dispatch"):
-            active = np.zeros(self.max_batch, bool)
-            active[active_rows] = True
-            self.attn_pages_spanned += len(active_rows) * self.max_pages
-            self.decode_rows += len(active_rows)
-            # the step attends over lengths + 1 tokens
-            self.decode_context_tokens += int(np.sum(
-                self._lengths[active_rows] + 1))
-            if self.use_kernel:
-                self.attn_pages_read += int(np.sum(
-                    self._lengths[active_rows] // self.page + 1))
-            logits, self.pools = self.step_fn(
-                self.params, self.pools,
-                jnp.asarray(self._last_tok[:, None]),
-                jnp.asarray(self._lengths),
-                jnp.asarray(self._tables),
-                jnp.asarray(active))
-        with TraceAnnotation("serve.sync"):
-            toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
-        with TraceAnnotation("serve.bookkeep"):
-            # GClock touch: every resident page of every active row was read
-            for i in active_rows:
-                if self._rows[i] is None:
-                    continue
-                self.pool.alloc.touch(self._reqs[self._rows[i]].pages)
-            for i in active_rows:
-                if self._rows[i] is None:
-                    continue
-                req = self._reqs[self._rows[i]]
-                # the page written this step diverged from any host copy
-                cur_tag = self._tag(req.rid, int(self._lengths[i]) // self.page)
-                self.pool.mark_redirtied(cur_tag)
-                req.out.append(int(toks[i]))
-                self._last_tok[i] = toks[i]
-                self._lengths[i] += 1
-                req.length += 1
-                if self._lengths[i] % self.page == 0 and self.use_flusher:
-                    tag = self._tag(req.rid, int(self._lengths[i]) // self.page - 1)
-                    self.pool.alloc.mark_full(tag)
-                    self.pool.note_page_full(self.pool.alloc.set_of(tag))
-                if len(req.out) >= req.max_new:
-                    req.state = "done"
-                    self._rows[i] = None
-                    self._tables[i, :] = self.scratch_page
-                    self._free(req)
+        prev, filled = self._inflight, []
+        if active_rows:
+            with TraceAnnotation("serve.dispatch"):
+                filled = self._dispatch(active_rows)
+        else:
+            self._inflight = None
+        if active_rows or prev is not None:
+            with TraceAnnotation("serve.sync"):
+                toks = None if prev is None else np.asarray(prev.tokens)
+            with TraceAnnotation("serve.bookkeep"):
+                if prev is not None:
+                    self._book(prev, toks)
+                for set_idx in filled:
+                    self.pool.note_page_full(set_idx)
         # resumption of preempted requests
         with TraceAnnotation("serve.requeue"):
             for req in list(self._reqs.values()):
                 if req.state == "preempted":
                     self._waiting.append(req.rid) if req.rid not in self._waiting else None
 
+    def _dispatch(self, active_rows: list[int]) -> list[int]:
+        """Dispatches one decode step of ``active_rows`` and does at once
+        what depends on no token value: lengths, re-dirtying the pages
+        written, full pages, the finish decision. Returns the sets of the
+        pages the step fills, for the flusher."""
+        active = np.zeros(self.max_batch, bool)
+        active[active_rows] = True
+        self.attn_pages_spanned += len(active_rows) * self.max_pages
+        self.decode_rows += len(active_rows)
+        # the step attends over lengths + 1 tokens
+        self.decode_context_tokens += int(np.sum(
+            self._lengths[active_rows] + 1))
+        if self.use_kernel:
+            self.attn_pages_read += int(np.sum(
+                self._lengths[active_rows] // self.page + 1))
+        # snapshots: the host edits these arrays while the step runs, and a
+        # device array may alias the numpy buffer it was made from
+        host = jnp.asarray(self._last_tok.copy())
+        carry = np.zeros(self.max_batch, bool)
+        prev = self._inflight
+        if prev is not None:
+            self.overlapped_steps += 1
+            for i, rid in prev.rows:
+                carry[i] = self._rows[i] == rid
+        tokens = self._next_tokens(host if prev is None else prev.tokens,
+                                   host, jnp.asarray(carry))
+        logits, self.pools = self.step_fn(
+            self.params, self.pools, tokens, jnp.asarray(self._lengths.copy()),
+            jnp.asarray(self._tables.copy()), jnp.asarray(active))
+        toks = jnp.argmax(logits[:, -1, :], axis=-1)
+        toks.copy_to_host_async()
+        filled, finishing = [], []
+        for i in active_rows:
+            req = self._reqs[self._rows[i]]
+            # the page written this step diverged from any host copy
+            cur_tag = self._tag(req.rid, int(self._lengths[i]) // self.page)
+            self.pool.mark_redirtied(cur_tag)
+            self._lengths[i] += 1
+            req.length += 1
+            if self._lengths[i] % self.page == 0 and self.use_flusher:
+                tag = self._tag(req.rid, int(self._lengths[i]) // self.page - 1)
+                self.pool.alloc.mark_full(tag)
+                filled.append(self.pool.alloc.set_of(tag))
+            # tokens served once this step is booked: one per consumed
+            # token past the prompt, plus the one the step computes
+            if req.length - len(req.prompt) + 1 >= req.max_new:
+                finishing.append(req.rid)
+        self._inflight = _InFlight(
+            toks, [(i, self._rows[i]) for i in active_rows], finishing)
+        return filled
+
+    def _book(self, step: _InFlight, toks: np.ndarray) -> None:
+        """The host's part of a step whose tokens ``toks`` it has read."""
+        # GClock touch: every resident page of every decoding row was read
+        for _, rid in step.rows:
+            self.pool.alloc.touch(self._reqs[rid].pages)
+        for i, rid in step.rows:
+            req = self._reqs[rid]
+            req.out.append(int(toks[i]))
+            if self._rows[i] == rid:
+                self._last_tok[i] = toks[i]
+            if len(req.out) >= req.max_new:
+                req.state = "done"
+                self._release(req)
+
+    def _drain(self) -> None:
+        """Reads and books the step in flight, if there is one."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self.drains += 1
+            self._book(step, np.asarray(step.tokens))
+
     def run(self, max_steps: int = 1000) -> None:
         for _ in range(max_steps):
             if all(r.state == "done" for r in self._reqs.values()):
                 break
             self.step()
+        self._drain()
 
     def stats(self) -> dict:
         s = self.pool.alloc.stats
@@ -426,7 +508,10 @@ class ServeEngine:
             "decode_context_tokens": self.decode_context_tokens,
             "prefill_tokens": self.prefill_tokens,
             "prefill_attn_pairs": self.prefill_attn_pairs,
+            "overlapped_steps": self.overlapped_steps,
+            "drains": self.drains,
         }
 
     def close(self):
+        self._drain()
         self.pool.close()
